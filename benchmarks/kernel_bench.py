@@ -10,7 +10,8 @@ Two sections:
     composed pipeline it replaces (pairwise_dist -> compare ->
     broadcast ids; dedupe_sorted -> x[ids] -> rowwise_dist -> compare),
     and price both against the analytic HBM-traffic roofline
-    (``launch.roofline.{linear,lsh}_scan_traffic`` / ``HBM_BW``).
+    (``launch.roofline.{linear,lsh}_scan_traffic`` over the chip's
+    published HBM bandwidth; ``None`` off a TPU — no chip, no peak).
 
 On CPU hosts both variants dispatch to the same jnp oracles, so the
 speedup hovers around 1.0 — the figure is meaningful on TPU, where the
@@ -90,8 +91,11 @@ def _composed_lsh(x, cands, q, r, metric):
     return ids, dists, mask
 
 
-def _route_rows(rng, scale: float) -> Dict[str, Dict[str, float]]:
-    """Fused vs composed per route x metric, plus the roofline terms."""
+def _route_rows(rng, scale: float, peaks) -> Dict[str, Dict[str, float]]:
+    """Fused vs composed per route x metric, plus the roofline terms
+    (``None`` when ``peaks`` is None)."""
+    roof = (lambda b: roofline.scan_memory_seconds(b, peaks)) \
+        if peaks is not None else (lambda b: None)
     n = max(int(16384 * scale), 512)
     nq = max(int(256 * scale), 32)
     d, W = 128, 2
@@ -125,10 +129,8 @@ def _route_rows(rng, scale: float) -> Dict[str, Dict[str, float]]:
             "candidates_per_s": nq * n / max(tf, 1e-12),
             "fused_bytes": traffic["fused_bytes"],
             "composed_bytes": traffic["composed_bytes"],
-            "roofline_fused_s": roofline.scan_memory_seconds(
-                traffic["fused_bytes"]),
-            "roofline_composed_s": roofline.scan_memory_seconds(
-                traffic["composed_bytes"]),
+            "roofline_fused_s": roof(traffic["fused_bytes"]),
+            "roofline_composed_s": roof(traffic["composed_bytes"]),
         }
 
         fused = jax.jit(lambda a, cd, b, m=metric, rr=r:
@@ -144,10 +146,8 @@ def _route_rows(rng, scale: float) -> Dict[str, Dict[str, float]]:
             "candidates_per_s": nq * c / max(tf, 1e-12),
             "fused_bytes": traffic["fused_bytes"],
             "composed_bytes": traffic["composed_bytes"],
-            "roofline_fused_s": roofline.scan_memory_seconds(
-                traffic["fused_bytes"]),
-            "roofline_composed_s": roofline.scan_memory_seconds(
-                traffic["composed_bytes"]),
+            "roofline_fused_s": roof(traffic["fused_bytes"]),
+            "roofline_composed_s": roof(traffic["composed_bytes"]),
         }
     out["_shapes"] = {"n": n, "nq": nq, "d": d, "candidates": c}
     return out
@@ -164,20 +164,25 @@ def main(scale: float | None = None, emit: str | None = None):
     if scale is None and emit is None:
         return rows          # legacy benchmarks.run call: micro rows only
 
-    routes = _route_rows(rng, scale if scale is not None else 0.12)
+    on_tpu = jax.default_backend() == "tpu"
+    peaks = (roofline.chip_peaks(jax.devices()[0].device_kind)
+             if on_tpu else None)
+    routes = _route_rows(rng, scale if scale is not None else 0.12, peaks)
     shapes = routes.pop("_shapes")
     for key, row in sorted(routes.items()):
+        roof = row["roofline_fused_s"]
         print(f"kernel_fused_{key},{1e6 * row['fused_s']:.1f},"
               f"{row['fused_speedup_composed']:.2f}x composed; "
-              f"{row['candidates_per_s'] / 1e6:.1f}M cand/s; "
-              f"roofline {1e6 * row['roofline_fused_s']:.1f}us")
+              f"{row['candidates_per_s'] / 1e6:.1f}M cand/s; roofline "
+              + (f"{1e6 * roof:.1f}us" if roof is not None
+                 else "not measured (no chip)"))
 
     out = {
         "impl": ops.resolve_impl(None),
-        "on_tpu": jax.default_backend() == "tpu",
+        "on_tpu": on_tpu,
         "backend": jax.default_backend(),
         "shapes": shapes,
-        "hbm_bw": roofline.HBM_BW,
+        "hbm_bw": peaks.hbm_bw if peaks is not None else None,
         "routes": routes,
     }
     if emit:

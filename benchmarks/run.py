@@ -13,6 +13,9 @@ import os
 import sys
 import time
 
+from benchmarks.hostdev import force_host_devices
+from repro.launch.compile_cache import enable_compile_cache
+
 
 def main() -> None:
     ap = argparse.ArgumentParser()
@@ -24,12 +27,13 @@ def main() -> None:
                     help="run a streaming benchmark and write its JSON; "
                          "--emit BENCH_streaming.json runs the single-host "
                          "bench, --emit BENCH_sharded.json the mesh-sharded "
-                         "one (>= 2 host devices forced), --emit "
+                         "one (>= 2 host devices forced under "
+                         "JAX_PLATFORMS=cpu), --emit "
                          "BENCH_lsm.json the LSM compaction-stall bench, "
                          "--emit BENCH_async.json the serving-thread stall "
                          "comparison (tick-based vs async CompactionDriver), "
                          "--emit BENCH_rebalance.json the skewed-stream "
-                         "placement comparison (>= 2 host devices forced), "
+                         "placement comparison (ditto), "
                          "--emit BENCH_obs.json the observability overhead "
                          "+ misroute-rate bench, --emit BENCH_kernels.json "
                          "the fused-vs-composed kernel comparison, --emit "
@@ -44,16 +48,12 @@ def main() -> None:
                          "paper tables")
     args = ap.parse_args()
     scale = 0.03 if args.quick else args.scale
-
-    def force_two_host_devices():
-        # must precede the first jax import in this process
-        flags = os.environ.get("XLA_FLAGS", "")
-        if "host_platform_device_count" not in flags:
-            os.environ["XLA_FLAGS"] = (
-                flags + " --xla_force_host_platform_device_count=2").strip()
+    emit = os.path.basename(args.emit) if args.emit else ""
+    if any(k in emit for k in ("rebalance", "recovery", "sharded")):
+        force_host_devices(2)           # before the first jax import
+    enable_compile_cache()
 
     if args.emit and "rebalance" in os.path.basename(args.emit):
-        force_two_host_devices()
         from benchmarks import sharded_bench
         print("name,us_per_call,derived")
         t0 = time.time()
@@ -78,7 +78,6 @@ def main() -> None:
         return
 
     if args.emit and "recovery" in os.path.basename(args.emit):
-        force_two_host_devices()
         from benchmarks import recovery_bench
         print("name,us_per_call,derived")
         t0 = time.time()
@@ -182,7 +181,6 @@ def main() -> None:
         return
 
     if args.emit and "sharded" in os.path.basename(args.emit):
-        force_two_host_devices()
         from benchmarks import sharded_bench
         print("name,us_per_call,derived")
         t0 = time.time()

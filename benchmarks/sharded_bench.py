@@ -32,6 +32,11 @@ import json
 import time
 from typing import Dict
 
+if __name__ == "__main__":
+    # before jax is imported: XLA reads the device count at start-up
+    from benchmarks.hostdev import force_host_devices
+    force_host_devices(2)
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -229,8 +234,6 @@ def skew_main(scale: float = 0.12,
 
 if __name__ == "__main__":
     import argparse
-    import os
-    import sys
 
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--skew", action="store_true",
@@ -240,13 +243,9 @@ if __name__ == "__main__":
     ap.add_argument("--scale", type=float, default=0.12)
     ap.add_argument("--emit", metavar="PATH", default=None)
     args = ap.parse_args()
-    flags = os.environ.get("XLA_FLAGS", "")
-    if len(jax.devices()) < 2 and "host_platform_device_count" not in flags:
-        # sharding needs >= 2 devices, and the flag must precede the
-        # jax import (already done at module top) — re-exec once with
-        # it set; the env check makes the re-exec terminate
-        os.environ["XLA_FLAGS"] = (
-            flags + " --xla_force_host_platform_device_count=2").strip()
-        os.execv(sys.executable, [sys.executable] + sys.argv)
+    if len(jax.devices()) < 2:
+        raise SystemExit("the sharded bench needs >= 2 devices: run it "
+                         "with JAX_PLATFORMS=cpu (two host devices are "
+                         "forced) or on a multi-chip host")
     run = skew_main if args.skew else main
     print(json.dumps(run(args.scale, emit=args.emit), indent=2))

@@ -32,7 +32,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.core.cost_model import CostModel
 from repro.core.engine import (SegmentEstimate, TableSegment,
@@ -87,7 +87,7 @@ def build_sharded(family, params, x: jax.Array, *, num_buckets: int, m: int,
     spec_x = P(data_axis)
     fn = shard_map(_build, mesh=mesh, in_specs=(spec_x,),
                    out_specs=(P(data_axis), P(data_axis), P(data_axis)),
-                   check_rep=False)
+                   check_vma=False)
     x = jax.device_put(x, NamedSharding(mesh, P(data_axis)))
     perm, starts, registers = jax.jit(fn)(x)
     return ShardedIndexState(x=x, perm=perm, starts=starts,
@@ -154,7 +154,7 @@ def make_query_fn(family, *, num_buckets: int, mesh: Mesh, n_total: int,
         _query, mesh=mesh,
         in_specs=((sharded, sharded, sharded, sharded), rep, rep, rep),
         out_specs=(sharded, sharded, sharded, rep, rep, sharded),
-        check_rep=False)
+        check_vma=False)
 
     @jax.jit
     def query(state, params, queries, r):

@@ -18,7 +18,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -59,7 +59,7 @@ def gpipe(stage_fn: Callable, stage_params, xs: jax.Array, *, mesh: Mesh,
         local, mesh=mesh,
         in_specs=(P(axis), P(*([None] * xs.ndim))),
         out_specs=P(*([None] * xs.ndim)),
-        check_rep=False)
+        check_vma=False)
     return fn(stage_params, xs)
 
 

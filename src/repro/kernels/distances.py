@@ -36,7 +36,7 @@ def _dot_kernel(q_ref, x_ref, qn_ref, xn_ref, out_ref, *, mode: str):
     @pl.when(k == 0)
     def _init():
         if mode == "l2":
-            out_ref[...] = qn_ref[...][:, None] + xn_ref[...][None, :]
+            out_ref[...] = qn_ref[...] + xn_ref[...]
         else:  # cosine: inputs pre-normalized, distance = 1 - dot
             out_ref[...] = jnp.ones_like(out_ref)
 
@@ -67,7 +67,9 @@ def pairwise_dot_pallas(q: jax.Array, x: jax.Array, qn: jax.Array,
     """Blocked (Q, d) x (N, d) -> (Q, N) squared-L2 or cosine distances.
 
     Shapes must already be padded: Q % tq == N % tn == d % td == 0.
-    ``qn``/``xn`` are squared norms (ignored for cosine but still tiled).
+    ``qn``/``xn`` are (Q,)/(N,) squared norms (ignored for cosine but
+    still tiled), passed to the kernel as a (tq, 1) column and a (1, tn)
+    row: 1-D blocks do not lower on the TPU.
     """
     nq, d = q.shape
     nn = x.shape[0]
@@ -79,14 +81,15 @@ def pairwise_dot_pallas(q: jax.Array, x: jax.Array, qn: jax.Array,
         in_specs=[
             pl.BlockSpec((tq, td), lambda i, j, k: (i, k)),
             pl.BlockSpec((tn, td), lambda i, j, k: (j, k)),
-            pl.BlockSpec((tq,), lambda i, j, k: (i,)),
-            pl.BlockSpec((tn,), lambda i, j, k: (j,)),
+            pl.BlockSpec((tq, 1), lambda i, j, k: (i, 0)),
+            pl.BlockSpec((1, tn), lambda i, j, k: (0, j)),
         ],
         out_specs=pl.BlockSpec((tq, tn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((nq, nn), jnp.float32),
         interpret=interpret,
     )(q.astype(jnp.float32), x.astype(jnp.float32),
-      qn.astype(jnp.float32), xn.astype(jnp.float32))
+      qn.astype(jnp.float32).reshape(nq, 1),
+      xn.astype(jnp.float32).reshape(1, nn))
 
 
 @functools.partial(jax.jit, static_argnames=("tq", "tn", "td", "interpret"))

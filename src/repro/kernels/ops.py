@@ -53,6 +53,26 @@ def pad_to(x: jax.Array, mult: int, axis: int, value=0) -> jax.Array:
     return jnp.pad(x, widths, constant_values=value)
 
 
+def _round_up(v: int, mult: int) -> int:
+    return -(-v // mult) * mult
+
+
+def _dot_tiles(nq: int, d: int, interpret: bool):
+    """(tq, tn, td) for the MXU distance kernels.
+
+    A small query batch (the 32-row chunks of ``linear_search``) gets a
+    query tile just big enough for it, in multiples of 32 (the int8
+    mask's sublane tile), instead of being padded to 256 rows; ``d``
+    pads only to the next multiple of 128 lanes, so a 128-wide corpus
+    is never copied to 256 columns.  Interpret mode keeps 128 tiles.
+    """
+    cap = 128 if interpret else _dist.DEFAULT_TQ
+    tq = min(cap, _round_up(nq, 32))
+    tn = min(_dist.DEFAULT_TN, cap)
+    td = min(_dist.DEFAULT_TD, cap, _round_up(d, 128))
+    return tq, tn, td
+
+
 def metric_radius_transform(metric: str, r: float) -> float:
     """Map a user radius to the raw-kernel comparison value.
 
@@ -86,9 +106,7 @@ def pairwise_dist(q: jax.Array, x: jax.Array, metric: str,
         x = x / jnp.maximum(jnp.linalg.norm(x, axis=-1, keepdims=True), 1e-12)
 
     if metric in ("l2", "cosine"):
-        tq, tn, td = _dist.DEFAULT_TQ, _dist.DEFAULT_TN, _dist.DEFAULT_TD
-        tq, tn, td = min(tq, 128 if interpret else tq), \
-            min(tn, 128 if interpret else tn), min(td, 128 if interpret else td)
+        tq, tn, td = _dot_tiles(nq, q.shape[1], interpret)
         qp = pad_to(pad_to(q, tq, 0), td, 1)
         xp = pad_to(pad_to(x, tn, 0), td, 1)
         qn = jnp.sum(qp.astype(jnp.float32) ** 2, axis=-1)
@@ -176,9 +194,7 @@ def fused_linear_scan(q: jax.Array, x: jax.Array, r, metric: str,
         q = q / jnp.maximum(jnp.linalg.norm(q, axis=-1, keepdims=True), 1e-12)
         x = x / jnp.maximum(jnp.linalg.norm(x, axis=-1, keepdims=True), 1e-12)
     if metric in ("l2", "cosine"):
-        tq, tn, td = _dist.DEFAULT_TQ, _dist.DEFAULT_TN, _dist.DEFAULT_TD
-        tq, tn, td = min(tq, 128 if interpret else tq), \
-            min(tn, 128 if interpret else tn), min(td, 128 if interpret else td)
+        tq, tn, td = _dot_tiles(nq, q.shape[1], interpret)
         qp = pad_to(pad_to(q, tq, 0), td, 1)
         xp = pad_to(pad_to(x, tn, 0), td, 1)
         qn = jnp.sum(qp.astype(jnp.float32) ** 2, axis=-1)

@@ -118,7 +118,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     mf = rl.model_flops(cfg, shape)
     terms = rl.terms_from_cost(
         {"flops": costs.flops, "bytes accessed": costs.bytes}, wire, mf,
-        chips)
+        chips, rl.chip_peaks(rl.V5E))
 
     rec.update({
         "status": "ok", "chips": chips,

@@ -85,7 +85,8 @@ def main():
     wire = sum(costs.wire.values())
     terms = rl.terms_from_cost(
         {"flops": costs.flops, "bytes accessed": costs.bytes}, wire,
-        2.0 * q * n * d, chips)  # useful = one full scan equivalent
+        2.0 * q * n * d, chips,  # useful = one full scan equivalent
+        rl.chip_peaks(rl.V5E))
     rec = {
         "arch": "paper-hybrid-lsh-index", "shape": f"n={n},d={d},Q={q}",
         "mesh": "2x16x16" if args.multi_pod else "16x16", "tag": "",

@@ -1,4 +1,4 @@
-"""Roofline terms from a compiled dry-run artifact (TPU v5e model).
+"""Roofline terms from a compiled dry-run artifact, priced per chip kind.
 
 The SPMD-partitioned module is per-device, so cost_analysis FLOPs/bytes
 and HLO tensor shapes are already per-chip quantities:
@@ -17,10 +17,37 @@ import dataclasses
 import re
 from typing import Dict, Optional
 
-# TPU v5e-like hardware model (per chip).
-PEAK_FLOPS_BF16 = 197e12        # FLOP/s
-HBM_BW = 819e9                  # B/s
-ICI_LINK_BW = 50e9              # B/s per link
+
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks."""
+
+    flops_bf16: float     # FLOP/s
+    hbm_bw: float         # B/s
+    hbm_bytes: float      # B
+    ici_link_bw: float    # B/s per link
+
+
+# Keyed by ``jax.Device.device_kind``.  Source: Google Cloud
+# documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB of HBM at 819 GB/s,
+# 1,600 Gbit/s of chip-to-chip interconnect over four links.
+V5E = "TPU v5 lite"
+PEAKS: Dict[str, ChipPeaks] = {
+    V5E: ChipPeaks(flops_bf16=197e12, hbm_bw=819e9, hbm_bytes=16e9,
+                   ici_link_bw=50e9),
+}
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """The peaks of ``device_kind``; a kind without published peaks in
+    ``PEAKS`` is an error (no default: a roofline priced against another
+    chip's peaks is wrong, not approximate)."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r} (known: {sorted(PEAKS)})") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -74,6 +101,7 @@ class RooflineTerms:
     bytes_per_chip: float
     wire_bytes_per_chip: float
     model_flops_per_chip: float
+    peak_flops: float
 
     @property
     def dominant(self) -> str:
@@ -93,22 +121,24 @@ class RooflineTerms:
     @property
     def roofline_fraction(self) -> float:
         """Useful-compute time / achievable step time: the score."""
-        model_t = self.model_flops_per_chip / PEAK_FLOPS_BF16
+        model_t = self.model_flops_per_chip / self.peak_flops
         return model_t / max(self.bound_s, 1e-30)
 
 
 def terms_from_cost(cost: Dict[str, float], wire_bytes: float,
-                    model_flops_global: float, chips: int) -> RooflineTerms:
+                    model_flops_global: float, chips: int,
+                    peaks: ChipPeaks) -> RooflineTerms:
     flops = float(cost.get("flops", 0.0))
     byts = float(cost.get("bytes accessed", 0.0))
     return RooflineTerms(
-        compute_s=flops / PEAK_FLOPS_BF16,
-        memory_s=byts / HBM_BW,
-        collective_s=wire_bytes / ICI_LINK_BW,
+        compute_s=flops / peaks.flops_bf16,
+        memory_s=byts / peaks.hbm_bw,
+        collective_s=wire_bytes / peaks.ici_link_bw,
         flops_per_chip=flops,
         bytes_per_chip=byts,
         wire_bytes_per_chip=wire_bytes,
         model_flops_per_chip=model_flops_global / chips,
+        peak_flops=peaks.flops_bf16,
     )
 
 
@@ -148,9 +178,9 @@ def lsh_scan_traffic(nq: int, c: int, d: int,
                                     + intermediate)}
 
 
-def scan_memory_seconds(n_bytes: float) -> float:
+def scan_memory_seconds(n_bytes: float, peaks: ChipPeaks) -> float:
     """Memory-roofline seconds for ``n_bytes`` of HBM traffic."""
-    return float(n_bytes) / HBM_BW
+    return float(n_bytes) / peaks.hbm_bw
 
 
 def model_flops(cfg, shape) -> float:

@@ -24,6 +24,8 @@ def main():
     ap.add_argument("--radius", type=float, default=0.3)
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     import jax
     import jax.numpy as jnp
     from repro.configs import get_config, reduced_config

@@ -19,7 +19,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.models.common import apply_rope, dense_init
@@ -236,7 +236,7 @@ def flash_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
         in_specs=(P(bspec), P(bspec, seq_axes), P(bspec, seq_axes),
                   P(bspec)),
         out_specs=P(bspec),
-        check_rep=False)
+        check_vma=False)
     out = fn(qg, k_cache, v_cache, lengths)
     return out.reshape(b, h, hd).astype(q.dtype)
 

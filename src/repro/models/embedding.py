@@ -16,7 +16,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.models.common import dense_init
@@ -46,7 +46,7 @@ def embed(table: jax.Array, ids: jax.Array, par) -> jax.Array:
     fn = shard_map(local, mesh=mesh,
                    in_specs=(P(ma, None), P(par.batch(), None)),
                    out_specs=P(par.batch(), None, None),
-                   check_rep=False)
+                   check_vma=False)
     return fn(table, ids)
 
 
@@ -93,7 +93,7 @@ def softmax_xent(head: jax.Array, h: jax.Array, labels: jax.Array, par,
                 in_specs=(P(ma, None), P(par.batch(), None, None),
                           P(par.batch(), None)),
                 out_specs=P(par.batch(), None),
-                check_rep=False)
+                check_vma=False)
             nll = fn(head, hc, lc)
         else:
             logits = jnp.einsum("bcd,vd->bcv", hc.astype(jnp.float32),
@@ -142,5 +142,5 @@ def greedy_sample(head: jax.Array, h_last: jax.Array, par) -> jax.Array:
     fn = shard_map(local, mesh=mesh,
                    in_specs=(P(ma, None), P(bspec, None)),
                    out_specs=P(bspec),
-                   check_rep=False)
+                   check_vma=False)
     return fn(head, h_last)

@@ -116,7 +116,7 @@ def _moe_apply_local(params, x: jax.Array, *, top_k: int,
     all-to-all of the (E, C, D) dispatch buffers.
     """
     import jax
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     b, s, d = x.shape
@@ -161,7 +161,7 @@ def _moe_apply_local(params, x: jax.Array, *, top_k: int,
         in_specs=(P(taxes, None), P(None, None)),
         out_specs=(P(None, taxes, None), P(taxes), P(taxes), P(taxes),
                    P(taxes), P(taxes), P()),
-        check_rep=False)
+        check_vma=False)
     buf, se, sg, st, pos, keep, aux = dis(xt, router)
 
     # Expert-parallel einsum: the only collective is the E<->C all-to-all.
@@ -186,6 +186,6 @@ def _moe_apply_local(params, x: jax.Array, *, top_k: int,
         in_specs=(P(None, taxes, None), P(taxes), P(taxes), P(taxes),
                   P(taxes), P(taxes)),
         out_specs=P(taxes, None),
-        check_rep=False)
+        check_vma=False)
     out = comb(y, se, sg, st, pos, keep)
     return out.reshape(b, s, d).astype(x.dtype), aux

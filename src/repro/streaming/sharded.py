@@ -62,7 +62,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.core.cost_model import CostModel
 from repro.core.engine import (QueryEngine, SegmentEstimate, TableSegment,
@@ -165,6 +165,12 @@ class _ShardMergeTask:
     row_off: int = 0
     steps: int = 0
     work_seconds: float = 0.0   # sum of this task's compact_step durations
+    # host copies of each input level's immutable leaves (x, ids,
+    # bucket_ids), fetched once per merge: slicing a sharded device leaf
+    # by shard is refused on an Explicit-axis mesh, and one fetch per
+    # level is cheaper than one device gather per staging step anyway
+    host: Dict[int, Tuple[np.ndarray, ...]] = dataclasses.field(
+        default_factory=dict)
 
     @property
     def pairs(self) -> List[Tuple[int, int]]:
@@ -404,7 +410,7 @@ class ShardedDynamicHybridIndex:
         sh = P(axis)
         fn = jax.jit(shard_map(
             _build, mesh=self.mesh, in_specs=(sh, sh, P()),
-            out_specs=(sh, sh, sh, sh), check_rep=False))
+            out_specs=(sh, sh, sh, sh), check_vma=False))
         self._fn_cache[key] = fn
         return fn
 
@@ -428,7 +434,7 @@ class ShardedDynamicHybridIndex:
         sh = P(axis)
         fn = jax.jit(shard_map(
             _build, mesh=self.mesh, in_specs=(sh, sh),
-            out_specs=(sh, sh, sh), check_rep=False))
+            out_specs=(sh, sh, sh), check_vma=False))
         self._fn_cache[key] = fn
         return fn
 
@@ -561,7 +567,7 @@ class ShardedDynamicHybridIndex:
         fn = jax.jit(shard_map(
             _ins, mesh=self.mesh,
             in_specs=((sh,) * 5, P(), sh, sh, sh),
-            out_specs=(sh,) * 5, check_rep=False))
+            out_specs=(sh,) * 5, check_vma=False))
         self._fn_cache[key] = fn
         return fn
 
@@ -646,7 +652,7 @@ class ShardedDynamicHybridIndex:
         sh = P(axis)
         fn = jax.jit(shard_map(_del, mesh=self.mesh,
                                in_specs=((sh,) * 3, sh, sh),
-                               out_specs=(sh, sh), check_rep=False))
+                               out_specs=(sh, sh), check_vma=False))
         self._fn_cache[key] = fn
         return fn
 
@@ -663,7 +669,7 @@ class ShardedDynamicHybridIndex:
         sh = P(axis)
         fn = jax.jit(shard_map(_del, mesh=self.mesh,
                                in_specs=((sh,) * 5, sh, sh),
-                               out_specs=sh, check_rep=False))
+                               out_specs=sh, check_vma=False))
         self._fn_cache[key] = fn
         return fn
 
@@ -854,6 +860,7 @@ class ShardedDynamicHybridIndex:
     def _stage(self, task: _ShardMergeTask, budget: int) -> None:
         pairs = task.pairs
         left = max(budget, 1)
+        live_h: Dict[int, np.ndarray] = {}   # this step's live bitmaps
         while left > 0 and not task.staged_done:
             uid, s = pairs[task.pair_idx]
             lvl = self._level_by_uid(uid)
@@ -864,16 +871,19 @@ class ShardedDynamicHybridIndex:
                 continue
             hi = min(n_rows, task.row_off + left)
             idx = np.arange(task.row_off, hi)
-            live = np.asarray(lvl.leaves["live"][s, task.row_off:hi])
+            if uid not in live_h:
+                live_h[uid] = np.asarray(lvl.leaves["live"])
+            live = live_h[uid][s, task.row_off:hi]
             idx = idx[live]
             if len(idx):
+                if uid not in task.host:
+                    task.host[uid] = tuple(np.asarray(lvl.leaves[k]) for k
+                                           in ("x", "ids", "bucket_ids"))
+                hx, hids, hbids = task.host[uid]
                 task.src.append((uid, s, idx))
-                task.rows.append(np.asarray(
-                    lvl.leaves["x"][s, task.row_off:hi])[live])
-                task.ids.append(np.asarray(
-                    lvl.leaves["ids"][s, task.row_off:hi])[live])
-                task.bids.append(np.asarray(
-                    lvl.leaves["bucket_ids"][s, task.row_off:hi])[live])
+                task.rows.append(hx[s, task.row_off:hi][live])
+                task.ids.append(hids[s, task.row_off:hi][live])
+                task.bids.append(hbids[s, task.row_off:hi][live])
             left -= hi - task.row_off
             task.row_off = hi
 
@@ -890,9 +900,11 @@ class ShardedDynamicHybridIndex:
         """
         S = self.shards
         surv: List[tuple] = []   # (origin shard, rows, ids, bids)
+        live_h = {u: np.asarray(self._level_by_uid(u).leaves["live"])
+                  for u in task.uids}
         for (uid, s, idx), rows, ids, bids in zip(task.src, task.rows,
                                                   task.ids, task.bids):
-            live = np.asarray(self._level_by_uid(uid).leaves["live"][s])[idx]
+            live = live_h[uid][s][idx]
             if live.any():
                 surv.append((s, rows[live], ids[live].astype(np.int64),
                              bids[live]))
@@ -954,16 +966,19 @@ class ShardedDynamicHybridIndex:
         dids = np.asarray(self._delta["ids"])[:, :C]
         dbids = np.asarray(self._delta["bucket_ids"])[:, :C]
         dlive = np.asarray(self._delta["live"])[:, :C]
+        # one host fetch per leaf (shard slicing happens on the host)
+        host = [{k: np.asarray(lvl.leaves[k])
+                 for k in ("live", "x", "ids", "bucket_ids")}
+                for lvl in self._levels]
         parts, total = [], 0
         for s in range(S):
             xs, es, bs = [dx[s][dlive[s]]], \
                 [dids[s][dlive[s]].astype(np.int64)], [dbids[s][dlive[s]]]
-            for lvl in self._levels:
-                live = np.asarray(lvl.leaves["live"][s, :lvl.n_pad])
-                xs.append(np.asarray(lvl.leaves["x"][s])[live])
-                es.append(np.asarray(
-                    lvl.leaves["ids"][s])[live].astype(np.int64))
-                bs.append(np.asarray(lvl.leaves["bucket_ids"][s])[live])
+            for lvl, h in zip(self._levels, host):
+                live = h["live"][s, :lvl.n_pad]
+                xs.append(h["x"][s][live])
+                es.append(h["ids"][s][live].astype(np.int64))
+                bs.append(h["bucket_ids"][s][live])
             x = np.concatenate(xs, axis=0)
             parts.append((x, np.concatenate(es), np.concatenate(bs, axis=0)))
             total += x.shape[0]
@@ -1097,7 +1112,7 @@ class ShardedDynamicHybridIndex:
             _query, mesh=self.mesh,
             in_specs=(tuple((sh,) * len(_LEAVES) for _ in n_pads),
                       (sh,) * 5, rep, rep, rep),
-            out_specs=(sh, sh, sh, rep, rep, sh), check_rep=False))
+            out_specs=(sh, sh, sh, rep, rep, sh), check_vma=False))
         self._fn_cache[key] = fn
         return fn
 

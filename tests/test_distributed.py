@@ -134,7 +134,7 @@ print("MAXDIFF", max(jax.tree_util.tree_leaves(d)))
 def test_compressed_psum_matches_plain():
     out = _run(r"""
 import jax, jax.numpy as jnp, numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.optim.compression import compressed_psum
 
@@ -145,7 +145,7 @@ def body(xs):
     return compressed_psum(xs[0], "pod", 8)
 
 fn = jax.jit(shard_map(body, mesh=mesh, in_specs=P("pod"),
-                       out_specs=P(None), check_rep=False))
+                       out_specs=P(None), check_vma=False))
 got = np.asarray(fn(x))
 want = np.asarray(x.mean(0))
 err = np.abs(got - want).max() / (np.abs(want).max() + 1e-12)

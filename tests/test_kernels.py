@@ -153,6 +153,28 @@ def test_fused_lsh_scan_handcrafted_candidates(metric):
         assert len(rep) == len(set(rep.tolist()))
 
 
+@pytest.mark.parametrize("metric", ["l2", "cosine"])
+def test_fused_lsh_scan_wide_rows(metric):
+    """Rows wider than fit 128 slabs at once (d = 1000 pads to 1024:
+    64 slabs in flight per group, two groups per 128-candidate tile),
+    candidates at every offset inside their 8-row slab, n not a
+    multiple of 8: identical to the oracle."""
+    n, q, d = 203, 9, 1000
+    qa, xa = _pts(q, d), _pts(n, d)
+    ids = jnp.sort(jnp.asarray(RNG.integers(0, n + 1, (q, 256),
+                                            dtype=np.int32)), axis=-1)
+    r = 44.0 if metric == "l2" else 0.99
+    ia, da, ma = ops.fused_lsh_scan(xa, ids, qa, r, metric,
+                                    impl="pallas_interpret")
+    ib, db, mb = ops.fused_lsh_scan(xa, ids, qa, r, metric, impl="ref")
+    np.testing.assert_array_equal(np.asarray(ia), np.asarray(ib))
+    np.testing.assert_array_equal(np.asarray(ma), np.asarray(mb))
+    ma_np = np.asarray(ma)
+    np.testing.assert_allclose(np.asarray(da)[ma_np], np.asarray(db)[ma_np],
+                               rtol=3e-4, atol=3e-4)
+    assert 0 < int(ma_np.sum()) < ma_np.size     # the radius splits
+
+
 @pytest.mark.parametrize("metric", ["l2", "hamming"])
 def test_fused_lsh_search_end_to_end(metric):
     """lsh_search with real tables + multi-probe tidx + cap truncation:
