@@ -6,9 +6,9 @@ Two questions, one synthetic corpus (docs/observability.md):
    modes: ``QueryTracer`` disabled (the production fast path — one
    attribute check), enabled at the default ``sample_every=16`` (one
    traced batch in sixteen — what a production service pays), and
-   enabled at ``sample_every=1`` (every batch traced: phase-synced
-   timings + the ``count_candidates`` pass that prices the actual
-   candidate set — the debug setting).  Passes are interleaved and the
+   enabled at ``sample_every=1`` (every batch traced: the
+   ``count_candidates`` pass that prices the actual candidate set and
+   its reads — the debug setting).  Passes are interleaved and the
    min per mode is taken, so container hiccups only inflate, never
    flatter; the sampled mode is timed over exactly ``sample_every``
    batches so each window amortizes exactly one traced batch.

@@ -27,16 +27,21 @@ reuse the traceable pieces (``Segment.estimate_terms`` +
 ``SegmentEstimate`` fields across shards with ``psum``/``pmax`` before
 finalizing — host-side partitioning only happens in the single-host
 ``QueryEngine.query``.
+
+The host path carries profiler spans (``jax.profiler.TraceAnnotation``,
+names ``repro.*``, on the device trace's clock, ~1 us each when no
+profiler runs); a span whose body reads from the device ends in
+``.sync`` and counts its ``reads`` (docs/observability.md).
 """
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Optional, Protocol, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import hll as hll_lib
 from repro.core import search as search_lib
@@ -242,7 +247,8 @@ class QueryResult:
     """Per-strategy buffers + per-query bookkeeping.
 
     ``neighbors(i)`` extracts the reported ids for query i regardless of
-    which strategy served it.
+    which strategy served it.  ``batch`` is the engine's call number,
+    stamped on the extraction spans so they join the query's spans.
     """
 
     route: RouteEstimate
@@ -251,33 +257,42 @@ class QueryResult:
     lsh_out: Optional[tuple]     # (ids, dists, mask) for the LSH group
     lin_out: Optional[tuple]     # (ids, dists, mask) for the linear group
     n_queries: int
+    batch: int = 0
 
-    def neighbors(self, i: int) -> np.ndarray:
-        for idx, out in ((self.lsh_idx, self.lsh_out),
-                         (self.lin_idx, self.lin_out)):
+    def _row(self, i: int):
+        """(route, group buffers, row) holding query ``i``."""
+        for route, idx, out in (("lsh", self.lsh_idx, self.lsh_out),
+                                ("linear", self.lin_idx, self.lin_out)):
             if out is None:
                 continue
             pos = np.nonzero(np.asarray(idx) == i)[0]
             if len(pos):
-                ids, _, mask = out
-                row = pos[0]
-                return np.asarray(ids[row])[np.asarray(mask[row])]
+                return route, out, pos[0]
         raise KeyError(i)
+
+    def neighbors(self, i: int) -> np.ndarray:
+        _, (ids, _, mask), row = self._row(i)
+        return np.asarray(ids[row])[np.asarray(mask[row])]
 
     def reported(self, i: int):
         """(ids, dists) reported for query ``i`` — ``neighbors`` plus
-        the distances, the pair the serving result cache stores."""
-        for idx, out in ((self.lsh_idx, self.lsh_out),
-                         (self.lin_idx, self.lin_out)):
-            if out is None:
-                continue
-            pos = np.nonzero(np.asarray(idx) == i)[0]
-            if len(pos):
-                ids, dists, mask = out
-                row = pos[0]
+        the distances, the pair the serving result cache stores.
+
+        Copies the row's whole padded buffers to the host: ``bytes`` on
+        the span is that copy, ``reported`` the ids it held."""
+        with TraceAnnotation("repro.result.reported",
+                             batch=self.batch) as span:
+            route, (ids, dists, mask), row = self._row(i)
+            width = ids.shape[-1]
+            with TraceAnnotation("repro.result.copy.sync", batch=self.batch,
+                                 reads=3):
                 m = np.asarray(mask[row])
-                return np.asarray(ids[row])[m], np.asarray(dists[row])[m]
-        raise KeyError(i)
+                out = np.asarray(ids[row])[m], np.asarray(dists[row])[m]
+            span.set_metadata(
+                route=route, reported=int(np.count_nonzero(m)),
+                bytes=width * (ids.dtype.itemsize + dists.dtype.itemsize
+                               + mask.dtype.itemsize))
+            return out
 
     def neighbor_sets(self):
         return {i: set(self.neighbors(i).tolist())
@@ -355,11 +370,12 @@ class QueryEngine:
         """Args: ``cost_model`` — Algorithm 2 constants (alpha, beta);
         ``impl`` — kernel impl override (e.g. ``"pallas_interpret"``);
         ``tracer`` — optional ``repro.obs.QueryTracer`` (duck-typed, the
-        engine never imports obs).  ``query`` takes the traced path only
-        while ``tracer.enabled`` is true."""
+        engine never imports obs).  ``query`` calls into it only while
+        ``tracer.enabled`` is true."""
         self.cost_model = cost_model
         self.impl = impl
         self.tracer = tracer
+        self._batches = 0
 
     # traceable pieces (also used inside shard_map by the sharded paths)
     def estimate(self, segments: Sequence[Segment],
@@ -377,26 +393,38 @@ class QueryEngine:
                               self.cost_model, impl=self.impl)
 
     def search_group(self, segments: Sequence[Segment], qbuckets: jax.Array,
-                     q: jax.Array, r, *, lsh_route: bool):
+                     q: jax.Array, r, *, lsh_route: bool, batch: int = 0):
         """Search every segment for one routed group; concat the buffers.
 
         Args:
           qbuckets/q: (G, L) buckets and (G, d) rows of the group.
           r: report radius; ``lsh_route`` picks the strategy.
+          batch: the engine call number the segment spans carry (under
+            ``jit``/``shard_map`` the spans mark tracing, not running).
 
         Returns sentinel-padded ``(ids, dists, mask)``, each (G, C) with
         C the concatenation of the per-segment output widths."""
-        parts = [s.search(qbuckets, q, r, lsh_route=lsh_route)
-                 for s in segments]
+        route = "lsh" if lsh_route else "linear"
+        parts = []
+        for i, s in enumerate(segments):
+            with TraceAnnotation("repro.engine.segment", batch=batch,
+                                 route=route, segment=i):
+                parts.append(s.search(qbuckets, q, r, lsh_route=lsh_route))
         if len(parts) == 1:
             return parts[0]
         return tuple(jnp.concatenate([p[i] for p in parts], axis=-1)
                      for i in range(3))
 
     # host-side pipeline (single-host indexes)
+    def next_batch(self) -> int:
+        """Number the next ``query`` call (its spans carry it)."""
+        self._batches += 1
+        return self._batches
+
     def query(self, segments: Sequence[Segment], queries: jax.Array,
               qbuckets: jax.Array, r: float,
-              force: Optional[str] = None) -> QueryResult:
+              force: Optional[str] = None, *,
+              batch: Optional[int] = None) -> QueryResult:
         """Hybrid r-NN reporting over the segments.
 
         Args:
@@ -405,35 +433,58 @@ class QueryEngine:
           r: report radius (every returned neighbor has dist <= r).
           force: None (hybrid routing) | "lsh" | "linear" — the two
             baselines of the paper's Figure 2.
+          batch: the call number from ``next_batch`` (a caller that
+            opens its own spans first takes one); None takes the next.
 
         Returns a ``QueryResult``; ``neighbors(i)``/``neighbor_sets()``
         extract reported ids regardless of which strategy served each
-        query.
+        query.  With an enabled tracer every batch counts its routes and
+        every ``sample_every``-th also prices its misroutes.
         """
+        batch = self.next_batch() if batch is None else batch
         tracer = self.tracer
-        if tracer is None or not tracer.enabled or not tracer.sample():
-            nq = queries.shape[0]
+        traced = tracer is not None and tracer.enabled
+        sampled = traced and tracer.sample()
+        nq = queries.shape[0]
+        with TraceAnnotation("repro.engine.estimate", batch=batch,
+                             segments=len(segments)):
             route = self.estimate(segments, qbuckets)
-            if force == "lsh":
-                use = np.ones(nq, bool)
-            elif force == "linear":
-                use = np.zeros(nq, bool)
-            else:
+        if force == "lsh":
+            use = np.ones(nq, bool)
+        elif force == "linear":
+            use = np.zeros(nq, bool)
+        else:
+            with TraceAnnotation("repro.engine.route.sync", batch=batch,
+                                 reads=1) as span:
                 use = np.asarray(route.use_lsh)
-            lsh_idx, lin_idx = partition_indices(use)
+                n_lsh = int(np.count_nonzero(use))
+                span.set_metadata(lsh_rows=n_lsh, linear_rows=nq - n_lsh)
+        if traced:
+            tracer.count_routes(use)
+        lsh_idx, lin_idx = partition_indices(use)
 
-            lsh_out = lin_out = None
-            if len(lsh_idx):
-                lsh_out = self.search_group(segments, qbuckets[lsh_idx],
-                                            queries[lsh_idx], float(r),
-                                            lsh_route=True)
-            if len(lin_idx):
-                lin_out = self.search_group(segments, qbuckets[lin_idx],
-                                            queries[lin_idx], float(r),
-                                            lsh_route=False)
-            return QueryResult(route=route, lsh_idx=lsh_idx, lin_idx=lin_idx,
-                               lsh_out=lsh_out, lin_out=lin_out, n_queries=nq)
-        return self._query_traced(segments, queries, qbuckets, r, force)
+        def group(idx, lsh_route):
+            if not len(idx):
+                return None
+            with TraceAnnotation(
+                    "repro.engine.search", batch=batch,
+                    route="lsh" if lsh_route else "linear",
+                    rows=int(np.count_nonzero(use == lsh_route)),
+                    padded_rows=len(idx)) as span:
+                out = self.search_group(segments, qbuckets[idx],
+                                        queries[idx], float(r),
+                                        lsh_route=lsh_route, batch=batch)
+                span.set_metadata(width=out[0].shape[-1])
+            return out
+
+        lsh_out = group(lsh_idx, True)
+        lin_out = group(lin_idx, False)
+        if sampled:
+            self._record_misroutes(segments, qbuckets, route, use, force,
+                                   batch)
+        return QueryResult(route=route, lsh_idx=lsh_idx, lin_idx=lin_idx,
+                           lsh_out=lsh_out, lin_out=lin_out, n_queries=nq,
+                           batch=batch)
 
     def count_candidates(self, segments: Sequence[Segment],
                          qbuckets: jax.Array) -> jax.Array:
@@ -444,91 +495,31 @@ class QueryEngine:
             total = total + s.count_candidates(qbuckets)
         return total
 
-    def _query_traced(self, segments: Sequence[Segment], queries: jax.Array,
-                      qbuckets: jax.Array, r: float,
-                      force: Optional[str]) -> QueryResult:
-        """``query`` with phase timing + span recording (same result).
-
-        Phase boundaries are ``block_until_ready``-synced so the timings
-        attribute device work to the phase that issued it — the reason
-        this is a separate method instead of timers in the fast path.
-        """
-        tracer = self.tracer
-        timings = {}
-        seg_seconds = None
-
-        t0 = time.perf_counter()
-        route = self.estimate(segments, qbuckets)
-        jax.block_until_ready(route.lsh_cost)
-        timings["estimate"] = time.perf_counter() - t0
-
-        nq = queries.shape[0]
-        if force == "lsh":
-            use = np.ones(nq, bool)
-        elif force == "linear":
-            use = np.zeros(nq, bool)
-        else:
-            use = np.asarray(route.use_lsh)
-        lsh_idx, lin_idx = partition_indices(use)
-
-        per_segment = (getattr(tracer, "per_segment_timing", False)
-                       and len(segments) > 1)
-
-        def timed_group(idx, lsh_route, label):
-            t0 = time.perf_counter()
-            if per_segment:
-                parts, seg_t = [], []
-                for si, s in enumerate(segments):
-                    ts = time.perf_counter()
-                    p = s.search(qbuckets[idx], queries[idx], float(r),
-                                 lsh_route=lsh_route)
-                    jax.block_until_ready(p[2])
-                    seg_t.append((f"seg{si}", time.perf_counter() - ts))
-                    parts.append(p)
-                if len(parts) == 1:
-                    out = parts[0]
-                else:
-                    out = tuple(jnp.concatenate([p[i] for p in parts],
-                                                axis=-1) for i in range(3))
-                seg_seconds[label] = seg_t
-            else:
-                out = self.search_group(segments, qbuckets[idx],
-                                        queries[idx], float(r),
-                                        lsh_route=lsh_route)
-            jax.block_until_ready(out[2])
-            timings[label] = time.perf_counter() - t0
-            return out
-
-        if per_segment:
-            seg_seconds = {}
-        lsh_out = lin_out = None
-        if len(lsh_idx):
-            lsh_out = timed_group(lsh_idx, True, "search_lsh")
-        if len(lin_idx):
-            lin_out = timed_group(lin_idx, False, "search_linear")
-
-        t0 = time.perf_counter()
-        cand_actual = np.asarray(self.count_candidates(segments, qbuckets))
-        timings["count_actual"] = time.perf_counter() - t0
-
-        coll = np.asarray(route.collisions).astype(np.float64)
-        lsh_cost_actual = np.asarray(self.cost_model.lsh_cost(
-            coll, cand_actual.astype(np.float64)))
-        tracer.record_batch(
+    def _record_misroutes(self, segments: Sequence[Segment],
+                          qbuckets: jax.Array, route: RouteEstimate,
+                          use: np.ndarray, force: Optional[str],
+                          batch: int) -> None:
+        """A sampled batch: price the actual candidate set (real device
+        work) and fold the batch into the tracer's misroute spans."""
+        with TraceAnnotation("repro.engine.misroute.sync", batch=batch,
+                             reads=4):
+            cand_actual = np.asarray(self.count_candidates(segments,
+                                                           qbuckets))
+            coll = np.asarray(route.collisions).astype(np.float64)
+            cand_est = np.asarray(route.cand_est).astype(np.float64)
+            lsh_cost_est = np.asarray(route.lsh_cost).astype(np.float64)
+        self.tracer.record_batch(
             use_lsh=use,
             collisions=coll,
-            cand_est=np.asarray(route.cand_est).astype(np.float64),
+            cand_est=cand_est,
             cand_actual=cand_actual,
-            lsh_cost_est=np.asarray(route.lsh_cost).astype(np.float64),
-            lsh_cost_actual=lsh_cost_actual,
-            linear_cost=float(np.asarray(route.linear_cost)),
+            lsh_cost_est=lsh_cost_est,
+            lsh_cost_actual=np.asarray(self.cost_model.lsh_cost(
+                coll, cand_actual.astype(np.float64))),
+            linear_cost=float(route.linear_cost),
             probes=int(qbuckets.shape[1]),
             forced=force,
-            phase_seconds=timings,
-            segment_seconds=seg_seconds,
             kernel_impl=ops.resolve_impl(self.impl))
-        return QueryResult(route=route, lsh_idx=lsh_idx, lin_idx=lin_idx,
-                           lsh_out=lsh_out, lin_out=lin_out, n_queries=nq)
 
 
 # ---------------------------------------------------------------------------

@@ -122,8 +122,8 @@ def lsh_candidate_counts(tables: LSHTables, qbuckets: jax.Array, cap: int,
     against the candidates actually scanned (cap-truncated, exactly
     like the search; tombstoned rows included — they are gathered and
     verified, so they are real work).  Per-route *kernel time* for the
-    verification itself is recorded by the tracer's phase histograms,
-    labeled with the backend that served it (``ops.resolve_impl``).
+    verification itself is in a profiler trace, under the engine's
+    ``repro.engine.search`` spans.
     """
     sentinel = tables.n
     cands = gather_candidates(tables, qbuckets, cap, sentinel, tidx=tidx)

@@ -50,7 +50,6 @@ class Observability:
     @classmethod
     def create(cls, enabled: bool = True, *, trace_capacity: int = 256,
                events_capacity: int = 512,
-               per_segment_timing: bool = False,
                trace_sample_every: int = 16) -> "Observability":
         """Build a bundle; ``enabled=False`` builds the no-op variant
         (null registry instruments, tracer/events short-circuit).
@@ -60,7 +59,6 @@ class Observability:
         return cls(
             registry=registry,
             tracer=QueryTracer(registry, capacity=trace_capacity,
-                               per_segment_timing=per_segment_timing,
                                enabled=enabled,
                                sample_every=trace_sample_every),
             events=EventLog(capacity=events_capacity, enabled=enabled),

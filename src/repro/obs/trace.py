@@ -32,21 +32,21 @@ Span fields (``SPAN_FIELDS``; docs/observability.md has the schema):
 ``cand_actual``, ``lsh_cost_est``, ``lsh_cost_actual``,
 ``linear_cost``, ``probes``, ``misroute``.
 
-Granularity: spans are per query; wall-time *phase* timings
-(``estimate`` / ``search_lsh`` / ``search_linear`` / ``count_actual``)
-are per batch (the engine executes routed groups batched, so per-query
-wall time does not exist), as are the optional per-segment timings
-(``per_segment_timing=True`` — searches each segment separately with
-device syncs; measurably slower, debug only).  Per-level merge/freeze
-timings live in the event log, not here.
+Granularity: spans are per query.  Where a batch's time goes is not
+kept here: the engine's host path carries profiler spans
+(``repro.engine.*``, ``repro.result.*``; docs/observability.md), read
+from a profiler trace.  Per-level merge/freeze timings live in the
+event log.
 
-Cost: a *traced* batch is not free — the ``count_candidates`` pass
+``repro_queries_total{route}`` counts every batch the engine serves
+while the tracer is enabled (``count_routes``, forced queries included).
+
+Cost: a *sampled* batch is not free — the ``count_candidates`` pass
 that prices the actual candidate set is real device work (roughly the
-gather+dedupe half of an LSH search), and the phase timings insert
-device syncs that cost pipelining.  The tracer therefore **samples**:
-with ``sample_every=N`` only every Nth query batch takes the traced
-path; the other N-1 run the byte-identical fast path (results never
-differ — tracing is observation only).  The default ``N=16`` keeps the
+gather+dedupe half of an LSH search) and its reads wait for the
+batch's device work.  The tracer therefore **samples**: with
+``sample_every=N`` only every Nth query batch is priced; results never
+differ — tracing is observation only.  The default ``N=16`` keeps the
 steady-state overhead of an *enabled* tracer under the 5% budget
 (benchmarks/obs_bench.py measures both the sampled and the
 every-batch figure); ``sample_every=1`` traces everything, for debug
@@ -56,8 +56,8 @@ only — an unbiased sample, since sampling is by arrival order, not by
 content.
 
 Thread safety: ``record_batch`` takes the tracer lock once per batch;
-registry instruments carry their own locks.  The engine's untraced
-path never calls in (it short-circuits on ``enabled``).
+registry instruments carry their own locks.  A disabled tracer is
+never called (the engine short-circuits on ``enabled``).
 """
 from __future__ import annotations
 
@@ -84,17 +84,15 @@ class QueryTracer:
     """Ring buffer of per-query route spans + calibration aggregates."""
 
     def __init__(self, registry: MetricsRegistry, capacity: int = 256,
-                 per_segment_timing: bool = False, enabled: bool = True,
-                 sample_every: int = 16):
+                 enabled: bool = True, sample_every: int = 16):
         self.enabled = bool(enabled)
-        self.per_segment_timing = bool(per_segment_timing)
         self.capacity = max(int(capacity), 1)
         self.sample_every = max(int(sample_every), 1)
         self._lock = threading.Lock()
         self._calls = 0            # query batches seen while enabled
         self._sampled = 0          # of those, batches actually traced
         self._spans: deque = deque(maxlen=self.capacity)
-        self._batches: deque = deque(maxlen=64)   # batch-level phase info
+        self._batches: deque = deque(maxlen=64)   # batch-level info
         # cumulative aggregates (never ring-evicted)
         self._queries = 0          # routed (non-forced) queries
         self._misroutes = 0
@@ -106,7 +104,7 @@ class QueryTracer:
         # registry series (null instruments when the registry is off)
         self._m_queries = {
             s: registry.counter("repro_queries_total",
-                                help="queries served, by chosen route",
+                                help="queries served, by route",
                                 labels={"route": s})
             for s in ("lsh", "linear")}
         self._m_misroutes = {
@@ -123,12 +121,6 @@ class QueryTracer:
                 help="|cand_est - cand_actual| / max(cand_actual, 1)",
                 labels={"route": s})
             for s in ("lsh", "linear")}
-        # phase histograms are labeled (phase, impl) so the exposition
-        # shows which kernel backend served each route (the fused Pallas
-        # path on TPU, the jnp oracles elsewhere); series are created
-        # lazily per observed backend (get-or-create is cheap)
-        self._registry = registry
-        self._m_phase: Dict[tuple, object] = {}
         self._last_impl: Optional[str] = None
         # multi-tenant context: extra fields stamped on every span
         # recorded while set (e.g. {"collection": name}); the serving
@@ -144,23 +136,12 @@ class QueryTracer:
         stay attributable per tenant."""
         self._context = {k: v for k, v in fields.items() if v is not None}
 
-    def _phase_hist(self, phase: str, impl: str):
-        key = (phase, impl)
-        h = self._m_phase.get(key)
-        if h is None:
-            h = self._registry.histogram(
-                "repro_query_phase_seconds",
-                help="wall seconds per traced query batch, by phase and "
-                     "kernel impl",
-                labels={"phase": phase, "impl": impl})
-            self._m_phase[key] = h
-        return h
-
     # ------------------------------------------------------------ sample
     def sample(self) -> bool:
-        """One call per query batch: True → the engine takes the traced
-        path for this batch.  Every ``sample_every``-th call samples
-        (the first always does, so short-lived tracers still trace)."""
+        """One call per query batch: True → the engine prices this
+        batch's misroutes (``record_batch``).  Every ``sample_every``-th
+        call samples (the first always does, so short-lived tracers
+        still trace)."""
         with self._lock:
             hit = (self._calls % self.sample_every) == 0
             self._calls += 1
@@ -169,23 +150,28 @@ class QueryTracer:
         return hit
 
     # ------------------------------------------------------------ record
+    def count_routes(self, use_lsh: np.ndarray) -> None:
+        """Every served batch: its (Q,) host route choices into
+        ``repro_queries_total{route}``."""
+        k = int(np.count_nonzero(use_lsh))
+        if k:
+            self._m_queries["lsh"].inc(k)
+        if len(use_lsh) > k:
+            self._m_queries["linear"].inc(len(use_lsh) - k)
+
     def record_batch(self, *, use_lsh: np.ndarray, collisions: np.ndarray,
                      cand_est: np.ndarray, cand_actual: np.ndarray,
                      lsh_cost_est: np.ndarray, lsh_cost_actual: np.ndarray,
                      linear_cost: float, probes: int,
                      forced: Optional[str],
-                     phase_seconds: Dict[str, float],
-                     segment_seconds: Optional[Dict[str, list]] = None,
-                     kernel_impl: Optional[str] = None
-                     ) -> None:
+                     kernel_impl: Optional[str] = None) -> None:
         """Fold one engine batch into spans + aggregates.
 
         All per-query arrays are (Q,) host numpy; ``linear_cost`` is
         the batch's scalar Eq. (2) cost; ``forced`` is the engine's
         strategy override (those queries get spans but do not count
         toward the misroute rate); ``kernel_impl`` is the resolved
-        kernel backend (``ops.resolve_impl``) that served the search
-        phases — it labels the phase histograms.
+        kernel backend (``ops.resolve_impl``) that served the batch.
         """
         use = np.asarray(use_lsh, bool)
         nq = int(use.shape[0])
@@ -222,8 +208,6 @@ class QueryTracer:
             self._last_impl = kernel_impl
             self._batches.append({
                 "n_queries": nq, "forced": forced,
-                "phase_seconds": dict(phase_seconds),
-                "segment_seconds": segment_seconds,
                 "kernel_impl": kernel_impl,
             })
             if forced is None:
@@ -242,13 +226,9 @@ class QueryTracer:
             sel = use if s == "lsh" else ~use
             k = int(sel.sum())
             if k and forced is None:
-                self._m_queries[s].inc(k)
                 self._m_misroutes[s].inc(int(mis[sel].sum()))
                 for e in rel_err[sel]:
                     self._m_rel_err[s].observe(float(e))
-        impl_label = kernel_impl or "auto"
-        for p, sec in phase_seconds.items():
-            self._phase_hist(p, impl_label).observe(float(sec))
 
     # ----------------------------------------------------------- readout
     @property

@@ -110,7 +110,6 @@ class RetrievalConfig:
     obs_trace_capacity: int = 256       # retained per-query spans
     obs_events_capacity: int = 512      # event-log ring size
     obs_trace_sample_every: int = 16    # trace every Nth batch (1 = all)
-    obs_per_segment_timing: bool = False
     obs_dump_path: Optional[str] = None  # shutdown() metrics dump target
 
 
@@ -178,7 +177,6 @@ class RetrievalService:
             enabled=rcfg.obs_enabled,
             trace_capacity=rcfg.obs_trace_capacity,
             events_capacity=rcfg.obs_events_capacity,
-            per_segment_timing=rcfg.obs_per_segment_timing,
             trace_sample_every=rcfg.obs_trace_sample_every)
         reg = self.obs.registry
         self._m_queries = reg.counter(

@@ -42,6 +42,7 @@ from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.cost_model import CostModel
 from repro.core.engine import (QueryEngine, QueryResult, RouteEstimate,
@@ -526,7 +527,8 @@ class DynamicHybridIndex:
                              seconds=self.stats.last_seconds)
 
     # ------------------------------------------------------------- query
-    def _segments(self, tidx: Optional[jax.Array] = None) -> List:
+    def _segments(self, tidx: Optional[jax.Array] = None,
+                  batch: int = 0) -> List:
         """The whole stack + delta as engine ``Segment`` adapters."""
         segs: List = []
         metric = self.family.metric
@@ -536,10 +538,12 @@ class DynamicHybridIndex:
                 cap=self.cap, impl=self.impl, live=f.tomb.live,
                 tomb_counts=f.tomb.counts, ext_ids=f.seg.ids,
                 n_live=f.n_live, n_scan=f.n_pad, tidx=tidx))
+        with TraceAnnotation("repro.index.delta_count.sync", batch=batch,
+                             reads=1):
+            n_scan = int(self.delta.count)
         segs.append(delta_lib.DeltaView(
             self.delta, metric, impl=self.impl,
-            n_live=self._n_delta_live, n_scan=int(self.delta.count),
-            tidx=tidx))
+            n_live=self._n_delta_live, n_scan=n_scan, tidx=tidx))
         return segs
 
     def _qbuckets(self, queries: jax.Array, num_probes: int
@@ -577,10 +581,14 @@ class DynamicHybridIndex:
         sentinel-padded buffers plus the ``RouteEstimate`` diagnostics.
         """
         assert self.delta is not None, "index is empty: build/insert first"
-        queries = jnp.asarray(queries)
-        qb, tidx = self._qbuckets(queries, num_probes)
-        return self._engine.query(self._segments(tidx), queries, qb,
-                                  float(r), force=force)
+        batch = self._engine.next_batch()
+        with TraceAnnotation("repro.index.query", batch=batch,
+                             rows=len(queries)):
+            queries = jnp.asarray(queries)
+            with TraceAnnotation("repro.index.hash", batch=batch):
+                qb, tidx = self._qbuckets(queries, num_probes)
+            return self._engine.query(self._segments(tidx, batch), queries,
+                                      qb, float(r), force=force, batch=batch)
 
     # ------------------------------------------------------ observability
     @property

@@ -211,7 +211,24 @@ def test_tracer_sampling_gates_batches():
     # batches 0 and 4 sample; 8 batches seen
     assert s["batches_seen"] == 8 and s["batches_traced"] == 2
     assert s["queries"] == 8
-    assert s["last_batch"]["phase_seconds"].keys() >= {"estimate"}
+
+
+def test_queries_total_counts_every_batch():
+    """The route counter sees every batch, not just the sampled ones;
+    the misroute denominator stays the sampled rows."""
+    obs = Observability.create(trace_sample_every=4)
+    x = _data(256)
+    idx = _dyn(obs=obs, delta_capacity=512).build(x)
+    q = jnp.asarray(x[:4])
+    for _ in range(8):
+        idx.query(q, 1.2)
+    counters = obs.registry.snapshot()["counters"]
+    by_route = {k: v for k, v in counters.items()
+                if k.startswith("repro_queries_total")}
+    assert set(by_route) <= {'repro_queries_total{route="lsh"}',
+                             'repro_queries_total{route="linear"}'}
+    assert sum(by_route.values()) == 32
+    assert obs.tracer.summary()["queries"] == 8
 
 
 # ------------------------------------------------------------ stats schemas

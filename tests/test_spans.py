@@ -1,0 +1,159 @@
+"""Profiler spans on the index's query and extraction path.
+
+A CPU profile of ``DynamicHybridIndex.query`` plus ``reported`` holds
+the ``repro.*`` spans docs/observability.md lists, nested by time on
+the caller's thread, with the counts they carry computed from shapes.
+Profiling changes no answer.
+"""
+import glob
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.profiler import ProfileData
+
+from repro.core import CostModel
+from repro.core.lsh import make_family
+from repro.streaming import CompactionPolicy, DynamicHybridIndex
+
+D, L, Q = 8, 4, 12
+
+# each span's parent: the innermost other span that covers it
+PARENT = {
+    "repro.index.query": None,
+    "repro.index.hash": "repro.index.query",
+    "repro.index.delta_count.sync": "repro.index.query",
+    "repro.engine.estimate": "repro.index.query",
+    "repro.engine.route.sync": "repro.index.query",
+    "repro.engine.search": "repro.index.query",
+    "repro.engine.segment": "repro.engine.search",
+    "repro.result.reported": None,
+    "repro.result.copy.sync": "repro.result.reported",
+}
+
+
+def _index():
+    rng = np.random.default_rng(0)
+    # a tight cluster (linear route) and spread rows (LSH route)
+    x = np.concatenate([rng.normal(size=(256, D)) * 0.05,
+                        rng.normal(size=(256, D)) * 3.0]).astype(np.float32)
+    idx = DynamicHybridIndex(
+        make_family("l2", d=D, L=L, r=1.0), num_buckets=256, m=32, cap=256,
+        key=0, delta_capacity=128, cost_model=CostModel(alpha=1.0, beta=1.0),
+        policy=CompactionPolicy(delta_fill=1.0, tombstone_ratio=2.0,
+                                fanout=2))
+    idx.build(x[:384])
+    idx.insert(x[384:])                 # a freeze: frozen levels + delta
+    idx.delete([3, 300])
+    return idx, jnp.asarray(x[::40][:Q])
+
+
+def _serve(idx, q):
+    res = idx.query(q, 1.2)
+    return res, [res.reported(i) for i in range(Q)]
+
+
+def _profiled(tmp_path, fn):
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        out = fn()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                      recursive=True)
+    spans = [(e.name, float(e.start_ns), float(e.start_ns + e.duration_ns),
+              dict(e.stats))
+             for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for e in line.events
+             if e.name.startswith("repro.")]
+    return out, sorted(spans, key=lambda s: (s[1], -s[2]))
+
+
+def _parent(span, spans):
+    cover = [s for s in spans if s is not span
+             and s[1] <= span[1] and span[2] <= s[2]]
+    return min(cover, key=lambda s: s[2] - s[1])[0] if cover else None
+
+
+@pytest.fixture(scope="module")
+def profiled(tmp_path_factory):
+    idx, q = _index()
+    _serve(idx, q)                      # compile outside the profile
+    (res, answers), spans = _profiled(tmp_path_factory.mktemp("prof"),
+                                      lambda: _serve(idx, q))
+    return idx, res, answers, spans
+
+
+def test_spans_nest_as_listed(profiled):
+    _, _, _, spans = profiled
+    assert {s[0] for s in spans} == set(PARENT)
+    for s in spans:
+        assert _parent(s, spans) == PARENT[s[0]], s
+
+
+def test_span_counts_from_shapes(profiled):
+    idx, res, answers, spans = profiled
+    by = {}
+    for s in spans:
+        by.setdefault(s[0], []).append(s[3])
+    n_seg = len(idx.stack.segments) + 1
+    assert n_seg >= 2
+    top, = by["repro.index.query"]
+    assert top == {"batch": res.batch, "rows": Q}
+    assert by["repro.engine.estimate"] == [{"batch": res.batch,
+                                            "segments": n_seg}]
+    assert by["repro.index.delta_count.sync"] == [{"batch": res.batch,
+                                                   "reads": 1}]
+    sync, = by["repro.engine.route.sync"]
+    use = np.asarray(res.route.use_lsh)
+    assert sync == {"batch": res.batch, "reads": 1,
+                    "lsh_rows": int(use.sum()),
+                    "linear_rows": int((~use).sum())}
+
+    groups = {"lsh": (res.lsh_idx, res.lsh_out),
+              "linear": (res.lin_idx, res.lin_out)}
+    searched = {a["route"]: a for a in by["repro.engine.search"]}
+    assert set(searched) == {r for r, (_, o) in groups.items()
+                             if o is not None}
+    assert set(searched) == {"lsh", "linear"}       # both routes ran
+    for route, a in searched.items():
+        idx_r, out = groups[route]
+        assert a == {"batch": res.batch, "route": route,
+                     "rows": len(set(np.asarray(idx_r).tolist())),
+                     "padded_rows": len(idx_r),
+                     "width": out[0].shape[-1]}
+    segs = sorted((a["route"], a["segment"])
+                  for a in by["repro.engine.segment"])
+    assert segs == sorted((r, i) for r in searched for i in range(n_seg))
+
+    reported = by["repro.result.reported"]
+    assert len(reported) == Q
+    for i, a in enumerate(reported):
+        route = "lsh" if use[i] else "linear"
+        width = groups[route][1][0].shape[-1]
+        assert a == {"batch": res.batch, "route": route,
+                     "reported": len(answers[i][0]),
+                     "bytes": width * (4 + 4 + 1)}     # ids, dists, mask
+    assert by["repro.result.copy.sync"] == [{"batch": res.batch,
+                                             "reads": 3}] * Q
+    # every device read of the request: the delta count, the route
+    # choice, and three row copies a query row
+    reads = sum(s[3]["reads"] for s in spans if s[0].endswith(".sync"))
+    assert reads == 2 + 3 * Q
+
+
+def test_profiling_changes_no_answer(profiled, tmp_path):
+    idx, q = _index()
+    _, plain = _serve(idx, q)
+    (_, traced), _ = _profiled(tmp_path, lambda: _serve(idx, q))
+    assert len(plain) == len(traced) == Q
+    for (ids0, d0), (ids1, d1) in zip(plain, traced):
+        np.testing.assert_array_equal(ids0, ids1)
+        np.testing.assert_array_equal(d0, d1)
+    _, _, answers, _ = profiled
+    assert [set(a.tolist()) for a, _ in answers] == \
+        [set(a.tolist()) for a, _ in plain]
