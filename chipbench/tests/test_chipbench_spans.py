@@ -1,25 +1,20 @@
-"""The program's own spans in the trace reduction: idle gaps labelled by
-the innermost program span under the harness span, and the metrics that
-read the spans' times and counts."""
-import json
-import types
+"""The program's own spans in a traced run (``chipbench/program_spans.py``):
+idle gaps labelled by the innermost program span under the harness span,
+and the readings of the spans' times and counts."""
 from pathlib import Path
 
 import pytest
 
-from chipbench import harness
+from chipbench import program_spans as ps
 from chipbench import trace as tl
 
 DEV, HOST = "/device:TPU:0", "/host:CPU"
 RECORDED = Path(__file__).parent / "data" / "trace_mixed_spans.json.gz"
-NEW_METRICS = ("route_sync_ms.index", "search_dispatch_ms.index",
-               "extract_wait_ms.index", "d2h_reads_per_request.index",
-               "extract_useful_share.index")
 
 
 def ev(plane, name, start, end, **args):
     line = "XLA Ops" if plane == DEV else "python"
-    return tl.Event(plane, line, name, float(start), float(end - start), args)
+    return ps.Span(plane, line, name, float(start), float(end - start), args)
 
 
 def harness_spans():
@@ -62,13 +57,11 @@ def program_spans():
 
 
 def read(name, red):
-    ctx = types.SimpleNamespace(trace=red)
-    return harness.load_module(harness.BENCH / "metrics"
-                               / f"{name}.py").read(ctx)
+    return ps.READINGS[name][1](red)
 
 
 def test_idle_gaps_named_by_program_span():
-    red = tl.reduce(harness_spans() + program_spans())
+    red = ps.ProgramReduced(harness_spans() + program_spans())
     assert red.n_requests == 2 and len(red.program) == 14
     # gaps [0,10] in route.sync; [45,120] (midpoint 82.5) and [140,200]
     # in the second and third row copies
@@ -80,12 +73,14 @@ def test_idle_gaps_named_by_program_span():
 
 def test_program_spans_change_no_other_reduction():
     plain = tl.reduce(harness_spans())
-    spans = tl.reduce(harness_spans() + program_spans())
+    spans = ps.ProgramReduced(harness_spans() + program_spans())
     assert (spans.window_s, spans.busy_s) == (plain.window_s, plain.busy_s)
     assert spans.top_ops() == plain.top_ops()
     assert {k.split("/")[0] for k, _ in spans.idle_by_label()} == \
         {k for k, _ in plain.idle_by_label()}
-    assert plain.program == []
+    assert plain.idle_by_label() == \
+        ps.ProgramReduced(harness_spans()).idle_by_label()
+    assert ps.ProgramReduced(harness_spans()).program == []
 
 
 @pytest.mark.parametrize("name,value", [
@@ -95,21 +90,25 @@ def test_program_spans_change_no_other_reduction():
     ("d2h_reads_per_request.index", (1 + 1 + 3 + 3 + 1 + 1 + 3) / 2),
     ("extract_useful_share.index", 100.0 * 8 * 150 / (900 + 900 + 450)),
 ])
-def test_metric_reads_program_spans(name, value):
-    assert read(name, tl.reduce(harness_spans() + program_spans())) == \
-        pytest.approx(value)
-    # a program without the spans, and a run without a trace, read nothing
-    assert read(name, tl.reduce(harness_spans())) is None
-    assert read(name, None) is None
+def test_reading_of_program_spans(name, value):
+    spans = ps.ProgramReduced(harness_spans() + program_spans())
+    assert read(name, spans) == pytest.approx(value)
+    # a program without the spans reads nothing
+    assert read(name, ps.ProgramReduced(harness_spans())) is None
 
 
-def test_new_metrics_are_in_the_spec():
-    spec = json.loads((harness.REPO / "BENCHMARK.json").read_text())
-    entries = {m["name"]: m for m in spec["per_layer"]}
-    for name in NEW_METRICS:
-        assert entries[name]["moves"] == "queries_per_s"
-        assert set(entries[name]["workloads"]) == {
-            w["name"] for w in spec["workloads"]}
+def test_report_sets_spans_beside_harness_metrics():
+    red = ps.ProgramReduced(harness_spans() + program_spans())
+    got = ps.report(red, {"query_call_ms.index": {"value": 39e-6},
+                          "extract_ms.index": {"value": 55e-6}})
+    assert set(got["readings"]) == set(ps.READINGS)
+    assert got["spans"]["repro.result.copy.sync"]["per_request"] == 1.5
+    assert got["idle_named_share"] == 1.0
+    # (38 + 40) / 2 ns against 39, (60 + 50) / 2 against 55
+    assert got["repro.index.query / query_call_ms.index"] == \
+        pytest.approx(1.0)
+    assert got["repro.result.reported / extract_ms.index"] == \
+        pytest.approx(1.0)
 
 
 def test_load_profile_keeps_program_args(tmp_path):
@@ -121,17 +120,27 @@ def test_load_profile_keeps_program_args(tmp_path):
         with jax.profiler.TraceAnnotation("repro.result.copy.sync", reads=3):
             pass
     jax.profiler.stop_trace()
-    got = {e.name: e.args for e in tl.load_profile(str(tmp_path))
+    got = {e.name: e.args for e in ps.load_profile(str(tmp_path))
            if e.name.startswith(("chipbench.", "repro."))}
     assert got == {"chipbench.request": {},
                    "repro.result.copy.sync": {"reads": 3}}
+
+
+def test_saved_slice_round_trips(tmp_path):
+    events = harness_spans() + program_spans()
+    kept = ps.slice_events(events, 1, 1)
+    assert {e.name for e in kept if e.name.startswith("chipbench.")} == \
+        {"chipbench.request", "chipbench.query", "chipbench.extract"}
+    assert all(e.start_ns >= 110 for e in kept if e.plane == HOST)
+    tl.save_events(kept, str(tmp_path / "s.json.gz"))
+    assert ps.load_events(str(tmp_path / "s.json.gz")) == kept
 
 
 def test_recorded_chip_trace_with_program_spans():
     """A slice of a traced window of the mixed cell on one v5e chip, with
     the program's spans: idle time in query and extraction is named by
     program span, and still sums to the window less the busy time."""
-    red = tl.reduce(tl.load_events(str(RECORDED)))
+    red = ps.ProgramReduced(ps.load_events(str(RECORDED)))
     assert red.program and 0 < red.busy_s < red.window_s
     idle = red.idle_by_label(100)
     assert sum(v for _, v in idle) == pytest.approx(
@@ -143,7 +152,7 @@ def test_recorded_chip_trace_with_program_spans():
     assert {k.split("/")[0] for k, _ in in_layers} == {"query", "extract"}
     assert any(k.startswith("query/") for k, _ in in_layers)
     assert any(k.startswith("extract/") for k, _ in in_layers)
-    for name in NEW_METRICS:
+    for name in ps.READINGS:
         assert read(name, red) > 0
     # 32 rows a request: the delta count, the route sync, 3 copies a row
     assert read("d2h_reads_per_request.index", red) == 2 + 3 * 32

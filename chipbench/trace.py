@@ -17,11 +17,7 @@ so the reduction can be checked on a small recorded trace
   ``XLA Modules`` line, hash dropped) and its short name.
 * Each idle gap of the device is labelled by the innermost harness span
   (``chipbench.<name>``) that covers its midpoint, ``between requests``
-  when none does, and the idle time is summed per label.  Where one of
-  the program's own spans (``repro.<name>``) covers the midpoint too,
-  the innermost of those is appended (``extract/result.copy.sync``).
-* The program's spans inside the window keep their arguments (counts
-  the program hands the profiler), for the metrics that read them.
+  when none does, and the idle time is summed per label.
 """
 from __future__ import annotations
 
@@ -39,7 +35,6 @@ MODULES_LINE = "XLA Modules"
 DEVICE_PLANE = "/device:"
 SPAN = "chipbench."
 REQUEST_SPAN = SPAN + "request"
-PROGRAM = "repro."
 
 
 @dataclasses.dataclass(frozen=True)
@@ -49,7 +44,6 @@ class Event:
     name: str
     start_ns: float
     dur_ns: float
-    args: Dict = dataclasses.field(default_factory=dict, hash=False)
 
     @property
     def end_ns(self) -> float:
@@ -81,14 +75,10 @@ def load_profile(trace_dir: str) -> List[Event]:
                            f"found {len(paths)}")
     out = []
     for plane in ProfileData.from_file(paths[0]).planes:
-        keep = not plane.name.startswith(DEVICE_PLANE)
         for line in plane.lines:
             for e in line.events:
-                args = (dict(e.stats) if keep and e.name.startswith(PROGRAM)
-                        else {})
                 out.append(Event(plane.name, line.name, e.name,
-                                 float(e.start_ns), float(e.duration_ns),
-                                 args))
+                                 float(e.start_ns), float(e.duration_ns)))
     return out
 
 
@@ -128,12 +118,8 @@ class Reduced:
         self.ops = [e for e in events
                     if e.plane.startswith(DEVICE_PLANE) and e.line == OPS_LINE
                     and e.end_ns > self.lo and e.start_ns < self.hi]
-        self.n_requests = len(req)
         self.spans = [e for e in events if e.name.startswith(SPAN)
                       and not e.plane.startswith(DEVICE_PLANE)]
-        self.program = [e for e in events if e.name.startswith(PROGRAM)
-                        and not e.plane.startswith(DEVICE_PLANE)
-                        and e.end_ns > self.lo and e.start_ns < self.hi]
         self.modules = sorted((e for e in events
                                if e.plane.startswith(DEVICE_PLANE)
                                and e.line == MODULES_LINE),
@@ -162,12 +148,6 @@ class Reduced:
         return (sum(self.busy_by_plane.values()) / len(self.busy_by_plane)
                 * 1e-9)
 
-    def program_ms_per_request(self, name: str) -> Optional[float]:
-        """Summed host time of the program's ``name`` spans over the
-        window's requests, in ms; ``None`` where it has none."""
-        durs = [e.dur_ns for e in self.program if e.name == name]
-        return sum(durs) * 1e-6 / self.n_requests if durs else None
-
     def kernel_seconds(self, names: Sequence[str]) -> float:
         """Summed device time of the ops whose short name matches one of
         the patterns."""
@@ -194,17 +174,22 @@ class Reduced:
         return [[n, v * 1e-9] for n, v in top]
 
     def idle_by_label(self, k: int = 10) -> List[List]:
-        harness = _Innermost(self.spans)
-        program = _Innermost(self.program)
+        # spans of one name never overlap (one caller), so a bisect per
+        # name finds the one covering t; the shortest cover is innermost
+        by_name: Dict[str, List[Event]] = {}
+        for e in sorted(self.spans, key=lambda e: e.start_ns):
+            by_name.setdefault(e.name, []).append(e)
+        starts = {n: [e.start_ns for e in v] for n, v in by_name.items()}
 
         def label(t: float) -> str:
-            cover = harness.at(t)
-            lab = ("between requests" if cover is None
-                   else cover.name[len(SPAN):])
-            inner = program.at(t)
-            if inner is not None:
-                lab += "/" + inner.name[len(PROGRAM):]
-            return lab
+            cover = []
+            for n, v in by_name.items():
+                i = bisect.bisect_right(starts[n], t) - 1
+                if i >= 0 and v[i].end_ns >= t:
+                    cover.append(v[i])
+            if not cover:
+                return "between requests"
+            return min(cover, key=lambda e: e.dur_ns).name[len(SPAN):]
 
         tot: Dict[str, float] = {}
         for s, e in self.gaps:
@@ -213,27 +198,6 @@ class Reduced:
         n_planes = max(len(self.busy_by_plane), 1)
         top = sorted(tot.items(), key=lambda kv: -kv[1])[:k]
         return [[n, v * 1e-9 / n_planes] for n, v in top]
-
-
-class _Innermost:
-    """The innermost of a set of host spans that covers a time.  Spans
-    of one name never overlap (one caller), so a bisect per name finds
-    the one covering t; the shortest cover is innermost."""
-
-    def __init__(self, spans: Sequence[Event]):
-        self.by_name: Dict[str, List[Event]] = {}
-        for e in sorted(spans, key=lambda e: e.start_ns):
-            self.by_name.setdefault(e.name, []).append(e)
-        self.starts = {n: [e.start_ns for e in v]
-                       for n, v in self.by_name.items()}
-
-    def at(self, t: float) -> Optional[Event]:
-        cover = []
-        for n, v in self.by_name.items():
-            i = bisect.bisect_right(self.starts[n], t) - 1
-            if i >= 0 and v[i].end_ns >= t:
-                cover.append(v[i])
-        return min(cover, key=lambda e: e.dur_ns) if cover else None
 
 
 def reduce(events: Sequence[Event]) -> Optional[Reduced]:
