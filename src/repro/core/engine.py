@@ -36,6 +36,7 @@ profiler runs); a span whose body reads from the device ends in
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional, Protocol, Sequence, Tuple, Union
 
 import jax
@@ -242,13 +243,57 @@ class TableSegment:
 # ---------------------------------------------------------------------------
 # Query result + host-side partitioning helpers
 # ---------------------------------------------------------------------------
+PAIRS_FLOOR = 1 << 16   # shortest packed buffer, in (id, dist) pairs
+
+
+def packed_length(total: int) -> int:
+    """Length of a group's packed buffers for ``total`` reported pairs:
+    a power of two, at least ``PAIRS_FLOOR``.  Each length is one
+    compile of ``_pack``, so the set of lengths stays small."""
+    return max(PAIRS_FLOOR, 1 << max(total - 1, 0).bit_length())
+
+
+def _first_rows(idx: np.ndarray) -> np.ndarray:
+    """(G,) bool: the group row is its query's first.  The power-of-two
+    padding repeats a query already in the group; it is never read."""
+    idx = np.asarray(idx)
+    first = np.zeros(idx.shape, bool)
+    first[np.unique(idx, return_index=True)[1]] = True
+    return first
+
+
+@functools.partial(jax.jit, static_argnames=("n_queries",))
+def _row_counts(masks, idxs, firsts, *, n_queries: int) -> jax.Array:
+    """(n_queries,) reported pairs of each query, in query order."""
+    counts = jnp.zeros(n_queries, jnp.int32)
+    for mask, idx, first in zip(masks, idxs, firsts):
+        counts = counts.at[jnp.where(first, idx, n_queries)].set(
+            jnp.sum(mask, axis=-1, dtype=jnp.int32), mode="drop")
+    return counts
+
+
+@functools.partial(jax.jit, static_argnames=("lengths", "impl"))
+def _pack(groups, firsts, *, lengths, impl=None):
+    """Each group's reported (id, dist) pairs of its first rows, in row
+    order and each row's in buffer order, in flat buffers of its
+    length (``ops.pack_reported``)."""
+    return [ops.pack_reported(ids, dists, mask & first[:, None], length,
+                              impl=impl)
+            for (ids, dists, mask), first, length
+            in zip(groups, firsts, lengths)]
+
+
 @dataclasses.dataclass
 class QueryResult:
     """Per-strategy buffers + per-query bookkeeping.
 
-    ``neighbors(i)`` extracts the reported ids for query i regardless of
-    which strategy served it.  ``batch`` is the engine's call number,
-    stamped on the extraction spans so they join the query's spans.
+    ``reported(i)``/``neighbors(i)`` return query i's answer regardless
+    of which strategy served it.  The first such call brings the whole
+    result to the host at once, packed on the device (one read of the
+    per-query counts, one of every group's packed pairs); every call
+    after it slices the host copy.  ``batch`` is the engine's call
+    number, stamped on the extraction spans so they join the query's
+    spans; ``impl`` the engine's kernel override.
     """
 
     route: RouteEstimate
@@ -258,41 +303,78 @@ class QueryResult:
     lin_out: Optional[tuple]     # (ids, dists, mask) for the linear group
     n_queries: int
     batch: int = 0
+    impl: Optional[str] = None
+    # host copy: ([(ids, dists)] a group, (n_queries, 3) rows of
+    # (group, start, end)); None until the first extraction
+    _host: Optional[tuple] = dataclasses.field(default=None, init=False,
+                                               repr=False)
 
-    def _row(self, i: int):
-        """(route, group buffers, row) holding query ``i``."""
+    def _route(self, i: int) -> str:
+        """The route that served query ``i``."""
         for route, idx, out in (("lsh", self.lsh_idx, self.lsh_out),
                                 ("linear", self.lin_idx, self.lin_out)):
-            if out is None:
-                continue
-            pos = np.nonzero(np.asarray(idx) == i)[0]
-            if len(pos):
-                return route, out, pos[0]
+            if out is not None and np.any(np.asarray(idx) == i):
+                return route
         raise KeyError(i)
 
+    def _fetch(self) -> int:
+        """Pack every query's answer on the device and copy the whole
+        result to the host in one transfer; returns the bytes moved."""
+        groups, idxs = [], []
+        for idx, out in ((self.lsh_idx, self.lsh_out),
+                         (self.lin_idx, self.lin_out)):
+            if out is not None:
+                groups.append(tuple(out))
+                idxs.append(np.asarray(idx, np.int32))
+        firsts = [_first_rows(idx) for idx in idxs]
+        with TraceAnnotation("repro.result.copy.sync", batch=self.batch,
+                             reads=2):
+            with TraceAnnotation("repro.result.compact",
+                                 batch=self.batch) as span:
+                counts = np.asarray(_row_counts(
+                    [g[2] for g in groups], idxs, firsts,
+                    n_queries=self.n_queries))
+                row_counts = [np.where(f, counts[idx], 0)
+                              for idx, f in zip(idxs, firsts)]
+                lengths = tuple(packed_length(int(c.sum()))
+                                for c in row_counts)
+                packed = _pack(groups, firsts, lengths=lengths,
+                               impl=self.impl)
+                span.set_metadata(pairs=int(counts.sum()),
+                                  padded_pairs=sum(lengths))
+            packed = jax.device_get(packed)
+        where = np.zeros((self.n_queries, 3), np.int64)
+        for g, (idx, first, c) in enumerate(zip(idxs, firsts, row_counts)):
+            start = np.cumsum(c) - c
+            where[idx[first]] = np.stack(
+                [np.full(first.sum(), g), start[first],
+                 (start + c)[first]], axis=1)
+        for pair in packed:
+            for a in pair:
+                a.flags.writeable = False     # callers share the copy
+        self._host = (packed, where)
+        return counts.nbytes + sum(a.nbytes for p in packed for a in p)
+
     def neighbors(self, i: int) -> np.ndarray:
-        _, (ids, _, mask), row = self._row(i)
-        return np.asarray(ids[row])[np.asarray(mask[row])]
+        return self.reported(i)[0]
 
     def reported(self, i: int):
         """(ids, dists) reported for query ``i`` — ``neighbors`` plus
         the distances, the pair the serving result cache stores.
 
-        Copies the row's whole padded buffers to the host: ``bytes`` on
-        the span is that copy, ``reported`` the ids it held."""
+        Read-only views of the host copy: ``bytes`` on the span is what
+        this call moved from the device (the whole result on the first
+        call, 0 after), ``reported`` the pairs query ``i`` holds."""
         with TraceAnnotation("repro.result.reported",
                              batch=self.batch) as span:
-            route, (ids, dists, mask), row = self._row(i)
-            width = ids.shape[-1]
-            with TraceAnnotation("repro.result.copy.sync", batch=self.batch,
-                                 reads=3):
-                m = np.asarray(mask[row])
-                out = np.asarray(ids[row])[m], np.asarray(dists[row])[m]
-            span.set_metadata(
-                route=route, reported=int(np.count_nonzero(m)),
-                bytes=width * (ids.dtype.itemsize + dists.dtype.itemsize
-                               + mask.dtype.itemsize))
-            return out
+            route = self._route(i)
+            moved = self._fetch() if self._host is None else 0
+            packed, where = self._host
+            g, lo, hi = where[i]
+            ids, dists = packed[g]
+            span.set_metadata(route=route, reported=int(hi - lo),
+                              bytes=moved)
+            return ids[lo:hi], dists[lo:hi]
 
     def neighbor_sets(self):
         return {i: set(self.neighbors(i).tolist())
@@ -484,7 +566,7 @@ class QueryEngine:
                                    batch)
         return QueryResult(route=route, lsh_idx=lsh_idx, lin_idx=lin_idx,
                            lsh_out=lsh_out, lin_out=lin_out, n_queries=nq,
-                           batch=batch)
+                           batch=batch, impl=self.impl)
 
     def count_candidates(self, segments: Sequence[Segment],
                          qbuckets: jax.Array) -> jax.Array:

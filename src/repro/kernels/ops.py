@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import compact as _compact
 from repro.kernels import distances as _dist
 from repro.kernels import fused_scan as _fs
 from repro.kernels import hamming as _ham
@@ -24,7 +25,8 @@ from repro.kernels import simhash as _sim
 
 __all__ = ["pairwise_dist", "hamming_dist", "simhash_fingerprint",
            "hll_merge_estimate", "pad_to", "metric_radius_transform",
-           "fused_linear_scan", "fused_lsh_scan", "resolve_impl"]
+           "fused_linear_scan", "fused_lsh_scan", "pack_reported",
+           "resolve_impl"]
 
 
 def _on_tpu() -> bool:
@@ -265,3 +267,33 @@ def hll_merge_estimate(regs: jax.Array,
     rp = pad_to(regs, tq, 0)
     return _hllm.hll_merge_estimate_pallas(rp, tq=tq,
                                            interpret=interpret)[:q]
+
+
+def pack_reported(ids: jax.Array, dists: jax.Array, mask: jax.Array,
+                  length: int, impl: Optional[str] = None):
+    """Every ``mask`` slot's (id, dist) of (G, W) buffers, row-major, in
+    flat (length,) buffers, zero past them: a route group's answers
+    without its padding.  ``length`` >= the number of slots."""
+    impl = _resolve(impl)
+    if impl == "ref":
+        return _ref.pack_reported(ids, dists, mask, length)
+    rows = 8 if impl == "pallas_interpret" else _compact.TILE_ROWS
+    lanes = _compact.LANES
+
+    def tiles(a):       # (G, W) -> (n, 128), n a multiple of ``rows``
+        a = pad_to(a.astype(jnp.int32), lanes, 1).reshape(-1, lanes)
+        return pad_to(a, rows, 0)
+
+    m = tiles(mask)
+    count = jnp.sum(m, axis=1)
+    end = jnp.cumsum(count)
+    out_i, out_d = _compact.pack_pallas(
+        (end - count).reshape(-1, 1, rows), m, tiles(ids),
+        tiles(jax.lax.bitcast_convert_type(dists, jnp.int32)),
+        length=_round_up(length, lanes), rows=rows,
+        interpret=impl == "pallas_interpret")
+    # the kernel writes the output rows it filled; zero the rest
+    tail = jnp.arange(length) >= end[-1]
+    return (jnp.where(tail, 0, out_i[:length]),
+            jax.lax.bitcast_convert_type(
+                jnp.where(tail, 0, out_d[:length]), dists.dtype))

@@ -147,3 +147,16 @@ def hll_merge_estimate(regs: jax.Array) -> jax.Array:
     from repro.core import hll as hll_lib
     merged = hll_lib.merge_registers(regs.astype(jnp.int32), axis=1)
     return hll_lib.estimate_cardinality(merged, int(regs.shape[-1]))
+
+
+def pack_reported(ids: jax.Array, dists: jax.Array, mask: jax.Array,
+                  length: int):
+    """(G, W) buffers -> (length,) ids and dists of the ``mask`` slots,
+    row-major, zero past them: each slot scattered to its rank among
+    the reported slots."""
+    rank = jnp.cumsum(mask.ravel(), dtype=jnp.int32) - 1
+    slot = jnp.where(mask.ravel(), rank, length)
+    out = [jnp.zeros(length, a.dtype).at[slot].set(a.ravel(), mode="drop",
+                                                   unique_indices=True)
+           for a in (ids, dists)]
+    return out[0], out[1]

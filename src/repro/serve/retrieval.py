@@ -521,8 +521,10 @@ class RetrievalService:
         for req, key in members:
             k = req.payload["tokens"].shape[0]
             pairs = [res.reported(off + j) for j in range(k)]
-            ids = [np.asarray(p[0]) for p in pairs]
-            dists = [np.asarray(p[1]) for p in pairs]
+            # copies: a cached answer must not keep the batch's whole
+            # host buffer (read-only views of it) alive
+            ids = [np.array(p[0]) for p in pairs]
+            dists = [np.array(p[1]) for p in pairs]
             self.cache.put(key, ids, dists)
             out[req.uid] = RequestResult(
                 uid=req.uid, ids=ids, dists=dists, n_queries=k,

@@ -10,7 +10,11 @@ tests compile each kernel through its ``ops`` wrapper for a described
   1,000,000 x 128 corpus (l2 and cosine);
 * the LSH route against the same corpus, with L * cap = 20 * 128
   candidates per query, and at the serving phase's 4096-dim width;
-* the HLL merge + estimate of a 256-query batch, L = 20, m = 64.
+* the HLL merge + estimate of a 256-query batch, L = 20, m = 64;
+* result extraction's count and pack programs at the benchmark cells'
+  group shapes: 16 linear rows of 1,097,729 slots and 16 LSH rows of
+  24,065 packed into 2^23 pairs (mixed), and 32 LSH rows at the floor
+  length (sparse).
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and a test worker that loads it keeps
@@ -25,12 +29,14 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import engine
 from repro.kernels import ops
 
 N, D = 1_000_000, 128          # chip_smoke.py index phase
 L, CAP, M = 20, 128, 64
 Q_CHUNK, Q_BATCH = 32, 256
 N_SERVE, D_SERVE = 65_536, 4096  # chip_smoke.py serving phase
+W_LINEAR, W_LSH = 1_097_729, 24_065  # group widths in the benchmark's cells
 
 
 @pytest.fixture(scope="module")
@@ -98,3 +104,33 @@ def test_kernel_compiles_for_v5e(case, one_chip, no_persistent_cache):
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 16e9, mem
+
+
+EXTRACTION = {    # (rows, width, packed length) of each route group
+    "mixed": [(16, W_LSH, None), (16, W_LINEAR, 1 << 23)],
+    "sparse": [(32, W_LSH, None)],
+}
+
+
+@pytest.mark.parametrize("cell", sorted(EXTRACTION))
+def test_extraction_compiles_for_v5e(cell, one_chip, no_persistent_cache):
+    groups = EXTRACTION[cell]
+    lengths = tuple(n or engine.PAIRS_FLOOR for _, _, n in groups)
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    bufs = [(s((g, w), jnp.int32), s((g, w), jnp.float32),
+             s((g, w), jnp.bool_)) for g, w, _ in groups]
+    idxs = [s((g,), jnp.int32) for g, _, _ in groups]
+    firsts = [s((g,), jnp.bool_) for g, _, _ in groups]
+    counts = engine._row_counts.lower(
+        [b[2] for b in bufs], idxs, firsts,
+        n_queries=sum(g for g, _, _ in groups)).compile()
+    packed = engine._pack.lower(bufs, firsts, lengths=lengths,
+                                impl="pallas").compile()
+    assert "tpu_custom_call" in packed.as_text()    # the packing kernel
+    for compiled in (counts, packed):
+        assert compiled.memory_analysis().temp_size_in_bytes < 16e9
+    assert [o.shape for pair in packed.out_info for o in pair] == \
+        [(n,) for n in lengths for _ in range(2)]

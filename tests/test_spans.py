@@ -2,7 +2,8 @@
 
 A CPU profile of ``DynamicHybridIndex.query`` plus ``reported`` holds
 the ``repro.*`` spans docs/observability.md lists, nested by time on
-the caller's thread, with the counts they carry computed from shapes.
+the caller's thread, with the counts they carry computed from shapes:
+four device reads a request, two of them the one compacted extraction.
 Profiling changes no answer.
 """
 import glob
@@ -15,6 +16,7 @@ import pytest
 from jax.profiler import ProfileData
 
 from repro.core import CostModel
+from repro.core.engine import packed_length
 from repro.core.lsh import make_family
 from repro.streaming import CompactionPolicy, DynamicHybridIndex
 
@@ -31,6 +33,7 @@ PARENT = {
     "repro.engine.segment": "repro.engine.search",
     "repro.result.reported": None,
     "repro.result.copy.sync": "repro.result.reported",
+    "repro.result.compact": "repro.result.copy.sync",
 }
 
 
@@ -132,18 +135,26 @@ def test_span_counts_from_shapes(profiled):
 
     reported = by["repro.result.reported"]
     assert len(reported) == Q
+    pairs = [sum(len(ids) for (ids, _), u in zip(answers, use) if u == lsh)
+             for lsh in (True, False)]                 # LSH, linear group
+    total, padded = sum(pairs), sum(packed_length(p) for p in pairs)
     for i, a in enumerate(reported):
         route = "lsh" if use[i] else "linear"
-        width = groups[route][1][0].shape[-1]
+        # the first call moves the whole result: the per-query counts,
+        # then each group's packed ids and distances; every later call
+        # slices it
+        moved = Q * 4 + padded * (4 + 4) if i == 0 else 0
         assert a == {"batch": res.batch, "route": route,
-                     "reported": len(answers[i][0]),
-                     "bytes": width * (4 + 4 + 1)}     # ids, dists, mask
+                     "reported": len(answers[i][0]), "bytes": moved}
     assert by["repro.result.copy.sync"] == [{"batch": res.batch,
-                                             "reads": 3}] * Q
+                                             "reads": 2}]
+    assert by["repro.result.compact"] == [{"batch": res.batch,
+                                           "pairs": total,
+                                           "padded_pairs": padded}]
     # every device read of the request: the delta count, the route
-    # choice, and three row copies a query row
+    # choice, then the counts and the packed pairs of the whole result
     reads = sum(s[3]["reads"] for s in spans if s[0].endswith(".sync"))
-    assert reads == 2 + 3 * Q
+    assert reads == 4
 
 
 def test_profiling_changes_no_answer(profiled, tmp_path):
