@@ -4,14 +4,14 @@ program's place, at the cell's own size, compared as a run compares.
     python3 chipbench/control.py --workload hybridlsh-densecore-l2.mixed \
         --seeds 1 2 3 [--rows linear]
 
-For each seed it draws the cell's inputs, takes as many requests of the
-pool as a run checks, answers them with the configuration reference's
-``control`` (rows of the ``dense`` kind labelled as the linear route
-would serve them, the others as the LSH route), and prints the numbers
-``check`` compares beside their limits.  ``--rows linear`` lowers only
-the rows labelled linear and answers the others in float32.  A sound
-comparison reads every control as not correct.  The benchmark's runs
-never run it.
+For each seed it draws the cell's inputs with its system adapter's
+``inputs``, takes as many requests of the pool as a run checks, answers
+them with the configuration reference's ``control`` (each row labelled
+linear or LSH route as the adapter's ``linear`` says), and prints the
+numbers ``check`` compares beside their limits.  ``--rows linear``
+lowers only the rows labelled linear and answers the others in float32.
+A sound comparison reads every control as not correct.  The benchmark's
+runs never run it.
 """
 import argparse
 import json
@@ -19,7 +19,7 @@ import sys
 from pathlib import Path
 
 REPO = Path(__file__).resolve().parents[1]
-sys.path[:0] = [str(REPO)]
+sys.path[:0] = [str(REPO), str(REPO / "src")]
 
 
 def control_readings(workload: str, seed: int, config=None, traffic=None,
@@ -29,23 +29,21 @@ def control_readings(workload: str, seed: int, config=None, traffic=None,
     the cell's own (tests use a small size)."""
     import numpy as np
 
-    from chipbench import clustered, generator, harness
+    from chipbench import generator, harness
 
     cell = harness.Cell(workload)
     cfg = config if config is not None else cell.config
     plan = generator.plan(traffic if traffic is not None else cell.traffic,
                           seed)
     ref_mod = harness.load_module(cell.reference_path)
-    inp = clustered.inputs(seed, cfg, plan)
+    inp = harness.load_module(cell.system_path).inputs(seed, cfg, plan)
     rng = np.random.default_rng([int(seed), 6])
     pick = rng.choice(plan.pool, size=min(plan.check_requests, plan.pool),
                       replace=False)
     ref = ref_mod.Reference(cfg, inp.r)
-    dense = plan.kind_names.index("dense") if "dense" in plan.kind_names \
-        else -1
     sample = []
     for i in sorted(pick):
-        linear = plan.kinds[i] == dense
+        linear = inp.linear[i]
         low = linear if rows == "linear" else np.ones_like(linear)
         sample.append((inp.requests[i], ref.control(inp.requests[i], low),
                        linear))

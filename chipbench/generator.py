@@ -16,6 +16,11 @@ A mix is a JSON file under ``chipbench/traffic/`` named by the cell's
 * ``check_requests``: requests of the window, drawn from the seed, whose
   answers the reference checks.
 
+Beyond these six, a mix holds exactly the keys its loop declares
+(``KEYS`` in ``loops/<loop>.py``: a rate, bursts, ...), and the plan
+hands them to the loop (``Plan.loop_params``).  A key missing or any
+other key is an error.
+
 Every seed gets the same sizes and the same count of each kind in every
 request; only the draws and their order differ, so seeds do not change
 the work.
@@ -23,10 +28,12 @@ the work.
 from __future__ import annotations
 
 import dataclasses
+from pathlib import Path
 from typing import Dict, List
 
 import numpy as np
 
+LOOPS = Path(__file__).resolve().parent / "loops"
 _KEYS = {"loop", "callers", "rows_per_request", "row_kinds",
          "pool_requests", "check_requests"}
 
@@ -38,6 +45,7 @@ class Plan:
     kind_names: List[str]
     kinds: np.ndarray          # (pool_requests, rows_per_request) int8
     check_requests: int
+    loop_params: Dict          # the mix's keys its loop declares
 
     @property
     def pool(self) -> int:
@@ -51,8 +59,13 @@ class Plan:
 
 
 def plan(traffic: Dict, seed: int) -> Plan:
-    unknown = set(traffic) - _KEYS
-    missing = _KEYS - set(traffic)
+    from chipbench import harness   # harness imports this module
+
+    if "loop" not in traffic:
+        raise ValueError("traffic keys: missing ['loop']")
+    own = set(harness.load_module(LOOPS / f"{traffic['loop']}.py").KEYS)
+    unknown = set(traffic) - _KEYS - own
+    missing = (_KEYS | own) - set(traffic)
     if unknown or missing:
         raise ValueError(f"traffic keys: unknown {sorted(unknown)}, "
                          f"missing {sorted(missing)}")
@@ -70,4 +83,5 @@ def plan(traffic: Dict, seed: int) -> Plan:
                       for _ in range(int(traffic["pool_requests"]))])
     return Plan(loop=str(traffic["loop"]), callers=int(traffic["callers"]),
                 kind_names=names, kinds=kinds.astype(np.int8),
-                check_requests=int(traffic["check_requests"]))
+                check_requests=int(traffic["check_requests"]),
+                loop_params={k: traffic[k] for k in sorted(own)})

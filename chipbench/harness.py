@@ -5,12 +5,18 @@ found by name:
 
 * ``configs/<config>.json``: sizes, source, guarantees, and the
   ``system`` adapter that builds and drives the program
-  (``systems/<system>.py``); ``configs/<config>.reference.py`` is the
-  plain reference that decides ``correct``;
+  (``systems/<system>.py``: ``System``, and ``inputs(seed, cfg, plan)``,
+  the requests, the radius and the rows the control answers as the
+  linear route); ``configs/<config>.reference.py`` is the plain
+  reference that decides ``correct``; ``configs/<config>.small.json``
+  holds the ``config`` and ``traffic`` overrides the CPU tests run the
+  configuration's cells at (runs never read it);
 * ``traffic/<traffic>.json``: a mix for ``generator.py``, driven by the
-  loop it names (``loops/<loop>.py``);
+  loop it names (``loops/<loop>.py``, whose ``KEYS`` are the mix's keys
+  of its own);
 * ``metrics/<metric>.py``: ``read(ctx)`` returns the metric's value, or
-  ``None`` where the run holds nothing to read.
+  ``None`` where the run holds nothing to read.  ``ctx.trace`` is the
+  traced run's ``trace.Reduced``: device numbers and the program's spans.
 
 A run: set-up (data, system, warm-up of the shapes the pool produces),
 then the window, then the device memory peak, the work counts (traced
@@ -48,8 +54,9 @@ def log(msg: str) -> None:
 def load_module(path: Path) -> types.ModuleType:
     if not path.is_file():
         raise FileNotFoundError(f"benchmark file {path} is missing")
+    rel = path.relative_to(BENCH) if path.is_relative_to(BENCH) else path
     tag = "chipbench_" + "".join(c if c.isalnum() else "_"
-                                 for c in str(path.relative_to(BENCH)))
+                                 for c in str(rel))
     spec = importlib.util.spec_from_file_location(tag, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
@@ -185,7 +192,7 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool, *,
                           seed)
     system_mod = load_module(cell.system_path)
     ref_mod = load_module(cell.reference_path)
-    loop = load_module(BENCH / "loops" / f"{plan.loop}.py")
+    loop = load_module(generator.LOOPS / f"{plan.loop}.py")
 
     log(f"imports and spec: {time.perf_counter() - t_start:.3f} s")
     system = system_mod.System(cfg, seed, plan, log=log)
@@ -234,20 +241,22 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool, *,
         f"{time.perf_counter() - t_ref:.3f} s")
     correct = all(numbers[k] <= lim for k, lim in ref_mod.LIMITS.items())
 
-    reduced = None
+    reduced, on_device = None, False
     if traced:
         try:
-            reduced = trace_lib.reduce(trace_lib.load_profile(tdir))
+            reduced = trace_lib.Reduced(trace_lib.load_profile(tdir))
         finally:
             shutil.rmtree(tdir, ignore_errors=True)
-        if reduced is not None:
+        # a trace with no device op (no chip) has no device numbers
+        on_device = bool(reduced.ops)
+        if on_device:
             device["busy_s"] = reduced.busy_s
             device["window_s"] = reduced.window_s
 
     ctx = types.SimpleNamespace(
         setup_s=setup_s, window_s=window_s, served=served, spans=rec.spans,
         work=work, trace=reduced, log=log,
-        peaks=peaks_for(device["kind"]) if reduced is not None else None)
+        peaks=peaks_for(device["kind"]) if on_device else None)
     metrics = {}
     for name, unit in cell.metrics(traced):
         value = load_module(BENCH / "metrics" / f"{name}.py").read(ctx)
@@ -255,7 +264,7 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool, *,
             metrics[name] = {"value": float(value), "unit": unit}
     out = {"correct": bool(correct), "attempted": len(served), "failed": 0,
            "metrics": metrics, "device": device}
-    if reduced is not None:
+    if on_device:
         out["breakdown"] = {"device_ops": reduced.top_ops(10),
                             "idle_gaps": reduced.idle_by_label(10)}
     log("reference tallies: " + json.dumps(tally, sort_keys=True))

@@ -6,6 +6,8 @@ from __future__ import annotations
 
 import time
 
+KEYS = frozenset()   # no mix keys of its own
+
 
 def run(system, plan, seconds: float, rec, sampler):
     if plan.callers != 1:

@@ -14,7 +14,7 @@ from __future__ import annotations
 
 import functools
 import time
-from typing import Dict, List
+from typing import Dict, List, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -49,6 +49,25 @@ def _distinct_candidates(perm, starts, qb, *, cap):
     return jnp.sum(first, axis=-1, dtype=jnp.int32)
 
 
+class Inputs(NamedTuple):
+    requests: np.ndarray        # (pool, rows, d) float32, host
+    r: float                    # the radius every request asks for
+    linear: np.ndarray          # (pool, rows) bool, see ``inputs``
+    corpus: clustered.Corpus
+
+
+def inputs(seed: int, cfg: Dict, plan) -> Inputs:
+    """The request pool, the radius and the corpus of one seed
+    (``clustered.inputs``), and ``linear``: the rows the control answers
+    as the linear route would serve them, the ``dense`` kind (the router
+    sends every dense row of ``data_seed`` 0 there), the others as the
+    LSH route."""
+    inp = clustered.inputs(seed, cfg, plan)
+    dense = (plan.kind_names.index("dense") if "dense" in plan.kind_names
+             else -1)
+    return Inputs(inp.requests, inp.r, plan.kinds == dense, inp.corpus)
+
+
 class System:
     """The index, the request pool and the radius of one seed."""
 
@@ -63,7 +82,7 @@ class System:
             t = time.perf_counter()
 
         n, d = int(cfg["rows"]), int(cfg["dim"])
-        inp = clustered.inputs(seed, cfg, plan)
+        inp = inputs(seed, cfg, plan)
         self.r, self.requests = inp.r, inp.requests
         x = np.asarray(inp.corpus.x)
         del inp

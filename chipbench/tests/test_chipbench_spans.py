@@ -1,10 +1,14 @@
-"""The program's own spans in a traced run (``chipbench/program_spans.py``):
-idle gaps labelled by the innermost program span under the harness span,
-and the readings of the spans' times and counts."""
+"""The program's own spans in a traced run, as ``trace.Reduced`` keeps
+them: idle gaps labelled by the innermost program span under the harness
+span, the per-layer metrics that read the spans' times and counts, and
+``chipbench/program_spans.py``'s report."""
+import json
+import types
 from pathlib import Path
 
 import pytest
 
+from chipbench import harness
 from chipbench import program_spans as ps
 from chipbench import trace as tl
 
@@ -12,9 +16,14 @@ DEV, HOST = "/device:TPU:0", "/host:CPU"
 RECORDED = Path(__file__).parent / "data" / "trace_mixed_spans.json.gz"
 
 
+READINGS = ["route_sync_ms.index", "search_dispatch_ms.index",
+            "extract_wait_ms.index", "d2h_reads_per_request.index",
+            "extract_useful_share.index"]
+
+
 def ev(plane, name, start, end, **args):
     line = "XLA Ops" if plane == DEV else "python"
-    return ps.Span(plane, line, name, float(start), float(end - start), args)
+    return tl.Event(plane, line, name, float(start), float(end - start), args)
 
 
 def harness_spans():
@@ -57,15 +66,18 @@ def program_spans():
 
 
 def read(name, red):
-    return ps.READINGS[name][1](red)
+    """The per-layer metric ``name`` of a run whose trace reduces to
+    ``red``."""
+    metric = harness.load_module(harness.BENCH / "metrics" / f"{name}.py")
+    return metric.read(types.SimpleNamespace(trace=red))
 
 
 def test_idle_gaps_named_by_program_span():
-    red = ps.ProgramReduced(harness_spans() + program_spans())
+    red = tl.Reduced(harness_spans() + program_spans())
     assert red.n_requests == 2 and len(red.program) == 14
     # gaps [0,10] in route.sync; [45,120] (midpoint 82.5) and [140,200]
     # in the second and third row copies
-    idle = dict((k, v) for k, v in red.idle_by_label())
+    idle = dict((k, v) for k, v in red.idle_by_label(program=True))
     assert idle == {"query/engine.route.sync": pytest.approx(10e-9),
                     "extract/result.copy.sync": pytest.approx(135e-9)}
     assert sum(idle.values()) == pytest.approx(red.window_s - red.busy_s)
@@ -73,14 +85,15 @@ def test_idle_gaps_named_by_program_span():
 
 def test_program_spans_change_no_other_reduction():
     plain = tl.reduce(harness_spans())
-    spans = ps.ProgramReduced(harness_spans() + program_spans())
+    spans = tl.reduce(harness_spans() + program_spans())
     assert (spans.window_s, spans.busy_s) == (plain.window_s, plain.busy_s)
     assert spans.top_ops() == plain.top_ops()
-    assert {k.split("/")[0] for k, _ in spans.idle_by_label()} == \
-        {k for k, _ in plain.idle_by_label()}
-    assert plain.idle_by_label() == \
-        ps.ProgramReduced(harness_spans()).idle_by_label()
-    assert ps.ProgramReduced(harness_spans()).program == []
+    # the harness's labels do not see the program's spans
+    assert spans.idle_by_label() == plain.idle_by_label()
+    assert {k.split("/")[0] for k, _ in spans.idle_by_label(program=True)} \
+        == {k for k, _ in plain.idle_by_label()}
+    assert plain.idle_by_label(program=True) == plain.idle_by_label()
+    assert plain.program == []
 
 
 @pytest.mark.parametrize("name,value", [
@@ -91,17 +104,18 @@ def test_program_spans_change_no_other_reduction():
     ("extract_useful_share.index", 100.0 * 8 * 150 / (900 + 900 + 450)),
 ])
 def test_reading_of_program_spans(name, value):
-    spans = ps.ProgramReduced(harness_spans() + program_spans())
+    spans = tl.Reduced(harness_spans() + program_spans())
     assert read(name, spans) == pytest.approx(value)
-    # a program without the spans reads nothing
-    assert read(name, ps.ProgramReduced(harness_spans())) is None
+    # a program without the spans, or a run without a trace, reads nothing
+    assert read(name, tl.Reduced(harness_spans())) is None
+    assert read(name, None) is None
 
 
 def test_report_sets_spans_beside_harness_metrics():
-    red = ps.ProgramReduced(harness_spans() + program_spans())
+    red = tl.Reduced(harness_spans() + program_spans())
     got = ps.report(red, {"query_call_ms.index": {"value": 39e-6},
                           "extract_ms.index": {"value": 55e-6}})
-    assert set(got["readings"]) == set(ps.READINGS)
+    assert got["idle_gaps"] == red.idle_by_label(1000, program=True)
     assert got["spans"]["repro.result.copy.sync"]["per_request"] == 1.5
     assert got["idle_named_share"] == 1.0
     # (38 + 40) / 2 ns against 39, (60 + 50) / 2 against 55
@@ -120,7 +134,7 @@ def test_load_profile_keeps_program_args(tmp_path):
         with jax.profiler.TraceAnnotation("repro.result.copy.sync", reads=3):
             pass
     jax.profiler.stop_trace()
-    got = {e.name: e.args for e in ps.load_profile(str(tmp_path))
+    got = {e.name: e.args for e in tl.load_profile(str(tmp_path))
            if e.name.startswith(("chipbench.", "repro."))}
     assert got == {"chipbench.request": {},
                    "repro.result.copy.sync": {"reads": 3}}
@@ -133,16 +147,16 @@ def test_saved_slice_round_trips(tmp_path):
         {"chipbench.request", "chipbench.query", "chipbench.extract"}
     assert all(e.start_ns >= 110 for e in kept if e.plane == HOST)
     tl.save_events(kept, str(tmp_path / "s.json.gz"))
-    assert ps.load_events(str(tmp_path / "s.json.gz")) == kept
+    assert tl.load_events(str(tmp_path / "s.json.gz")) == kept
 
 
 def test_recorded_chip_trace_with_program_spans():
     """A slice of a traced window of the mixed cell on one v5e chip, with
     the program's spans: idle time in query and extraction is named by
     program span, and still sums to the window less the busy time."""
-    red = ps.ProgramReduced(ps.load_events(str(RECORDED)))
+    red = tl.reduce(tl.load_events(str(RECORDED)))
     assert red.program and 0 < red.busy_s < red.window_s
-    idle = red.idle_by_label(100)
+    idle = red.idle_by_label(100, program=True)
     assert sum(v for _, v in idle) == pytest.approx(
         red.window_s - red.busy_s, rel=1e-6)
     in_layers = [(k, v) for k, v in idle
@@ -152,7 +166,55 @@ def test_recorded_chip_trace_with_program_spans():
     assert {k.split("/")[0] for k, _ in in_layers} == {"query", "extract"}
     assert any(k.startswith("query/") for k, _ in in_layers)
     assert any(k.startswith("extract/") for k, _ in in_layers)
-    for name in ps.READINGS:
+    for name in READINGS:
         assert read(name, red) > 0
     # 32 rows a request: the delta count, the route sync, 3 copies a row
     assert read("d2h_reads_per_request.index", red) == 2 + 3 * 32
+
+
+# what ``program_spans.py`` read on the recorded slice when it reduced
+# the program's spans itself (``ProgramReduced``, ``READINGS``)
+RECORDED_READINGS = {
+    "route_sync_ms.index": 0.627385,
+    "search_dispatch_ms.index": 326.502014,
+    "extract_wait_ms.index": 405.0269025,
+    "d2h_reads_per_request.index": 98.0,
+    "extract_useful_share.index": 40.00527280409772,
+}
+RECORDED_PROGRAM_IDLE = [
+    ["extract/result.copy.sync", 0.7969131450000001],
+    ["query/engine.estimate", 0.14340270800000002],
+    ["query/engine.segment", 0.023439125],
+    ["query/engine.search", 0.019952034],
+    ["query/engine.route.sync", 0.0008230700000000001],
+    ["query/index.query", 0.0007350270000000001],
+    ["request", 0.000675344],
+    ["query/index.delta_count.sync", 0.000633575],
+    ["query/index.hash", 0.000586981],
+    ["extract/result.reported", 0.000542526],
+]
+
+
+@pytest.mark.parametrize("name", sorted(RECORDED_READINGS))
+def test_recorded_reading_as_program_spans_read_it(name):
+    red = tl.reduce(tl.load_events(str(RECORDED)))
+    assert read(name, red) == RECORDED_READINGS[name]
+
+
+def test_recorded_program_idle_labels_as_program_spans_read_them():
+    red = tl.reduce(tl.load_events(str(RECORDED)))
+    assert red.idle_by_label(10, program=True) == RECORDED_PROGRAM_IDLE
+
+
+def test_readings_are_metrics_of_the_spec():
+    """Each reading is a per-layer metric of cells of the spec, and what
+    it reads is the program's: neither harness span nor device."""
+    spec = json.loads((harness.REPO / "BENCHMARK.json").read_text())
+    per_layer = {m["name"]: m for m in spec["per_layer"]}
+    cells = {w["name"] for w in spec["workloads"]}
+    for name in READINGS:
+        assert set(per_layer[name]["workloads"]) <= cells
+        assert per_layer[name]["workloads"]
+        assert per_layer[name]["moves"] == "queries_per_s"
+        assert per_layer[name]["source"] in ("program_span",
+                                             "program_counter")

@@ -1,5 +1,6 @@
 """Trace reduction: busy and idle share, kernel time by stored name, and
 idle gaps labelled by harness span."""
+import types
 from pathlib import Path
 
 import pytest
@@ -8,7 +9,8 @@ from chipbench import harness
 from chipbench import trace as tl
 
 DEV, HOST = "/device:TPU:0", "/host:CPU"
-RECORDED = Path(__file__).parent / "data" / "trace_mixed_small.json.gz"
+DATA = Path(__file__).parent / "data"
+RECORDED = DATA / "trace_mixed_small.json.gz"
 
 
 def ev(plane, line, name, start, end):
@@ -76,3 +78,75 @@ def test_recorded_chip_trace():
     assert labels <= {"query", "extract", "request", "between requests"}
     assert sum(v for _, v in red.idle_by_label(100)) == pytest.approx(
         red.window_s - red.busy_s, rel=1e-6)
+
+
+# What the reduction and the device-trace metrics read on the two
+# recorded slices before the reduction kept the program's spans
+# (``work`` below stands for a run's work counts).  The program's spans
+# must change none of it, to the bit.
+WORK = {"dim": 128.0, "live_rows": 990000.0, "linear_calls": 2.0,
+        "linear_rows": 32.0, "linear_pairs": 16e6, "lsh_rows": 32.0,
+        "lsh_candidates": 250000.0}
+PEAKS = {"flops": 197e12, "hbm_bytes_per_s": 819e9}
+SLICES = {
+    "trace_mixed_small.json.gz": {
+        "busy_s": 0.641987286, "window_s": 1.649334298,
+        "device_idle_share.index": 61.075975514576974,
+        "linear_scan_roofline": 30.07664101525781,
+        "lsh_scan_roofline": 0.7999261989799319,
+        "top_ops": [
+            ["jit_gather/fusion", 0.579477428],
+            ["jit_lsh_search/lsh_scan_pallas.1", 0.019692962],
+            ["jit_dynamic_slice/copy-done", 0.006317698],
+            ["jit_gather/copy.11", 0.005742049],
+            ["jit_squeeze/reduce", 0.005399725],
+            ["jit_linear_search/linear_scan_dot_pallas.1", 0.004560364],
+            ["jit_gather/while.1", 0.003957202],
+            ["jit_lsh_search/fusion.2", 0.0037606170000000004],
+            ["jit_gather/dynamic-update-slice.2", 0.003298033],
+            ["jit_linear_search/multiply_reduce_fusion", 0.001473726],
+        ],
+        "idle_by_label": [
+            ["extract", 0.7746949310000001],
+            ["query", 0.232652081],
+        ],
+    },
+    "trace_mixed_spans.json.gz": {
+        "busy_s": 0.633652105, "window_s": 1.6213556420000002,
+        "device_idle_share.index": 60.91837666051061,
+        "linear_scan_roofline": 30.266058823841142,
+        "lsh_scan_roofline": 1.2015566728332288,
+        "top_ops": [
+            ["jit_gather/fusion", 0.5778723370000001],
+            ["jit_lsh_search/lsh_scan_pallas.1", 0.013110423000000001],
+            ["jit_dynamic_slice/copy-done", 0.006845979],
+            ["jit_squeeze/reduce", 0.005948419000000001],
+            ["jit_gather/copy.11", 0.005854654],
+            ["jit_linear_search/linear_scan_dot_pallas.1", 0.004531355],
+            ["jit_gather/while.1", 0.003956449],
+            ["jit_gather/dynamic-update-slice.2", 0.003298038],
+            ["jit_lsh_search/fusion.2", 0.002507505],
+            ["jit_linear_search/multiply_reduce_fusion", 0.001473551],
+        ],
+        "idle_by_label": [
+            ["extract", 0.7974556730000001],
+            ["query", 0.18957252000000002],
+            ["request", 0.000675344],
+        ],
+    },
+}
+
+
+@pytest.mark.parametrize("name", sorted(SLICES))
+def test_recorded_slice_reads_as_before(name):
+    red = tl.reduce(tl.load_events(str(DATA / name)))
+    want = SLICES[name]
+    assert (red.busy_s, red.window_s) == (want["busy_s"], want["window_s"])
+    assert red.top_ops(10) == want["top_ops"]
+    assert red.idle_by_label(10) == want["idle_by_label"]
+    ctx = types.SimpleNamespace(trace=red, work=WORK, peaks=PEAKS,
+                                log=lambda *_: None)
+    for metric in ("device_idle_share.index", "linear_scan_roofline",
+                   "lsh_scan_roofline"):
+        m = harness.load_module(harness.BENCH / "metrics" / f"{metric}.py")
+        assert m.read(ctx) == want[metric], metric

@@ -18,6 +18,13 @@ so the reduction can be checked on a small recorded trace
 * Each idle gap of the device is labelled by the innermost harness span
   (``chipbench.<name>``) that covers its midpoint, ``between requests``
   when none does, and the idle time is summed per label.
+* The program's own host spans (``repro.<name>``, docs/observability.md)
+  inside the window are kept with the arguments the program gave them
+  (``Event.args``: host-only counts), for the per-layer metrics that read
+  them (``ms_per_request``, ``program``).  They never enter the busy
+  time or the harness's idle labels; ``idle_by_label(program=True)``
+  appends to a gap's label ``/`` and the innermost program span covering
+  its midpoint (``extract/result.copy.sync``).
 """
 from __future__ import annotations
 
@@ -35,6 +42,7 @@ MODULES_LINE = "XLA Modules"
 DEVICE_PLANE = "/device:"
 SPAN = "chipbench."
 REQUEST_SPAN = SPAN + "request"
+PROGRAM = "repro."
 
 
 @dataclasses.dataclass(frozen=True)
@@ -44,6 +52,7 @@ class Event:
     name: str
     start_ns: float
     dur_ns: float
+    args: Dict = dataclasses.field(default_factory=dict, hash=False)
 
     @property
     def end_ns(self) -> float:
@@ -65,7 +74,8 @@ def profile_options():
 
 
 def load_profile(trace_dir: str) -> List[Event]:
-    """Every event of the one ``.xplane.pb`` the profiler wrote."""
+    """Every event of the one ``.xplane.pb`` the profiler wrote, with the
+    arguments of the program's host spans."""
     from jax.profiler import ProfileData
 
     paths = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
@@ -75,10 +85,14 @@ def load_profile(trace_dir: str) -> List[Event]:
                            f"found {len(paths)}")
     out = []
     for plane in ProfileData.from_file(paths[0]).planes:
+        host = not plane.name.startswith(DEVICE_PLANE)
         for line in plane.lines:
             for e in line.events:
+                args = (dict(e.stats) if host and e.name.startswith(PROGRAM)
+                        else {})
                 out.append(Event(plane.name, line.name, e.name,
-                                 float(e.start_ns), float(e.duration_ns)))
+                                 float(e.start_ns), float(e.duration_ns),
+                                 args))
     return out
 
 
@@ -88,6 +102,7 @@ def save_events(events: Iterable[Event], path: str) -> None:
 
 
 def load_events(path: str) -> List[Event]:
+    """Events saved by ``save_events``, with or without arguments."""
     with gzip.open(path, "rt") as f:
         return [Event(*row) for row in json.load(f)]
 
@@ -106,13 +121,35 @@ def _clip(iv, lo, hi):
     return [(max(s, lo), min(e, hi)) for s, e in iv if e > lo and s < hi]
 
 
+class _Innermost:
+    """The innermost of a set of host spans that covers a time.  Spans of
+    one name never overlap (one caller), so a bisect per name finds the
+    one covering t; the shortest cover is innermost."""
+
+    def __init__(self, spans: Sequence[Event]):
+        self.by_name: Dict[str, List[Event]] = {}
+        for e in sorted(spans, key=lambda e: e.start_ns):
+            self.by_name.setdefault(e.name, []).append(e)
+        self.starts = {n: [e.start_ns for e in v]
+                       for n, v in self.by_name.items()}
+
+    def at(self, t: float) -> Optional[Event]:
+        cover = []
+        for n, v in self.by_name.items():
+            i = bisect.bisect_right(self.starts[n], t) - 1
+            if i >= 0 and v[i].end_ns >= t:
+                cover.append(v[i])
+        return min(cover, key=lambda e: e.dur_ns) if cover else None
+
+
 class Reduced:
-    """The device numbers of one traced window."""
+    """The device numbers and the program's spans of one traced window."""
 
     def __init__(self, events: Sequence[Event]):
         req = [e for e in events if e.name == REQUEST_SPAN]
         if not req:
             raise ValueError("no harness request span in the trace")
+        self.n_requests = len(req)
         self.lo = min(e.start_ns for e in req)
         self.hi = max(e.end_ns for e in req)
         self.ops = [e for e in events
@@ -120,6 +157,9 @@ class Reduced:
                     and e.end_ns > self.lo and e.start_ns < self.hi]
         self.spans = [e for e in events if e.name.startswith(SPAN)
                       and not e.plane.startswith(DEVICE_PLANE)]
+        self.program = [e for e in events if e.name.startswith(PROGRAM)
+                        and not e.plane.startswith(DEVICE_PLANE)
+                        and e.end_ns > self.lo and e.start_ns < self.hi]
         self.modules = sorted((e for e in events
                                if e.plane.startswith(DEVICE_PLANE)
                                and e.line == MODULES_LINE),
@@ -173,23 +213,28 @@ class Reduced:
         top = sorted(tot.items(), key=lambda kv: -kv[1])[:k]
         return [[n, v * 1e-9] for n, v in top]
 
-    def idle_by_label(self, k: int = 10) -> List[List]:
-        # spans of one name never overlap (one caller), so a bisect per
-        # name finds the one covering t; the shortest cover is innermost
-        by_name: Dict[str, List[Event]] = {}
-        for e in sorted(self.spans, key=lambda e: e.start_ns):
-            by_name.setdefault(e.name, []).append(e)
-        starts = {n: [e.start_ns for e in v] for n, v in by_name.items()}
+    def ms_per_request(self, name: str) -> Optional[float]:
+        """Summed host time of the program's ``name`` spans over the
+        window's requests, in ms; ``None`` where it has none."""
+        durs = [e.dur_ns for e in self.program if e.name == name]
+        return sum(durs) * 1e-6 / self.n_requests if durs else None
+
+    def idle_by_label(self, k: int = 10,
+                      program: bool = False) -> List[List]:
+        """Idle device time summed by the label of each gap, the largest
+        ``k``; with ``program`` the labels name the program span too."""
+        harness = _Innermost(self.spans)
+        inner = _Innermost(self.program) if program else None
 
         def label(t: float) -> str:
-            cover = []
-            for n, v in by_name.items():
-                i = bisect.bisect_right(starts[n], t) - 1
-                if i >= 0 and v[i].end_ns >= t:
-                    cover.append(v[i])
-            if not cover:
-                return "between requests"
-            return min(cover, key=lambda e: e.dur_ns).name[len(SPAN):]
+            cover = harness.at(t)
+            lab = ("between requests" if cover is None
+                   else cover.name[len(SPAN):])
+            if inner is not None:
+                span = inner.at(t)
+                if span is not None:
+                    lab += "/" + span.name[len(PROGRAM):]
+            return lab
 
         tot: Dict[str, float] = {}
         for s, e in self.gaps:
