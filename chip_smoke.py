@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
-"""Chip smoke test: the hybrid r-NN index and its serving path on a TPU.
+"""Chip smoke test: the hybrid r-NN index on a TPU.
 
-    python chip_smoke.py               # one chip: index + serving phases
+    python chip_smoke.py               # one chip: the index phase
     python chip_smoke.py --four-chips  # four chips: the sharded index only
 
 Drives the main path through the entry points a user calls, at a
@@ -20,11 +20,9 @@ device (direct differences; no index, no repo kernel).
   (|q|^2 + |x|^2)``; LSH and hybrid answers must be subsets of it; no
   deleted id may be reported; hybrid routing must send at least a
   quarter of the queries each way.
-* Serving phase: ``RetrievalService.submit`` -> ``drain_batches`` over
-  65,536 documents embedded by yi-6b at its published widths, depth cut
-  to two layers, random weights from the seed.  Every reported neighbor
-  must lie within the radius of its query, by the same reference on the
-  service's own embeddings.
+* The served path (``RetrievalService.submit`` -> ``drain_batches``
+  with yi-6b as the encoder) is the benchmark cell
+  ``yi6b-served.steady`` (``chipbench/``), checked by its reference.
 * ``--four-chips``: only the mesh-sharded index
   (``ShardedDynamicHybridIndex`` on ``jax.make_mesh((4,), ("data",))``),
   churned the same way; its forced-route answers must equal those of a
@@ -41,7 +39,6 @@ from __future__ import annotations
 
 import argparse
 import contextlib
-import dataclasses
 import functools
 import json
 import sys
@@ -54,14 +51,11 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
-from repro.configs import get_config  # noqa: E402
 from repro.core.lsh import make_family  # noqa: E402
 from repro.core.lsh.tables import bucket_counts  # noqa: E402
-from repro.data import clustered_dataset, lm_batch  # noqa: E402
+from repro.data import clustered_dataset  # noqa: E402
 from repro.kernels import ops  # noqa: E402
 from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
-from repro.models import ParallelConfig, init_params  # noqa: E402
-from repro.serve import RetrievalConfig, RetrievalService  # noqa: E402
 from repro.streaming import (DynamicHybridIndex,  # noqa: E402
                              ShardedDynamicHybridIndex)
 
@@ -76,11 +70,6 @@ N_INSERT = 2 * DELTA_CAP + DELTA_CAP // 2     # forces a level-0 freeze
 DELETE_FRAC = 0.01
 NQ = 256        # one routed batch: the linear route returns (Q, N) buffers
 MAX_OUT = 16_384                              # four-chip: per (shard, query)
-
-# Serving phase: yi-6b widths, 65,536 x 4096 f32 embeddings (~1 GB).
-N_DOCS, DOC_LEN, DOC_BATCH = 65_536, 16, 1024
-N_REQUESTS, ROWS_PER_REQUEST, REQUESTS_PER_BATCH = 48, 4, 16
-
 
 def log(msg: str) -> None:
     print(msg, flush=True)
@@ -310,89 +299,6 @@ def index_phase(seed: int, n: int = N, d: int = D) -> None:
         del res
 
 
-def serving_phase(seed: int, cfg=None, n_docs: int = N_DOCS) -> None:
-    base = get_config("yi-6b")
-    if cfg is None:
-        cfg = dataclasses.replace(base, n_layers=2, repeats=2)
-    log(f"== serving phase: RetrievalService.submit -> drain_batches; "
-        f"encoder {cfg.name} d_model {cfg.d_model}, heads {cfg.n_heads} "
-        f"(kv {cfg.n_kv_heads}), d_ff {cfg.d_ff}, vocab {cfg.vocab}; "
-        f"depth cut {base.n_layers} -> {cfg.n_layers} layers; random "
-        f"weights from seed {seed}")
-    par = ParallelConfig(mesh=None, attn_chunk_q=DOC_LEN,
-                         attn_chunk_k=DOC_LEN, logits_chunk=DOC_LEN)
-    rcfg = RetrievalConfig(coalesce_max_batch=REQUESTS_PER_BATCH)
-    svc = RetrievalService(cfg, par, init_params(cfg, jax.random.PRNGKey(
-        seed)), rcfg)
-
-    def tokens(step, batch):
-        return lm_batch(seed, step, batch=batch, seq=DOC_LEN,
-                        vocab=cfg.vocab)["tokens"]
-
-    n_batches = n_docs // DOC_BATCH
-    corpus = [{"tokens": tokens(i, DOC_BATCH)} for i in range(n_batches)]
-    # requests: half near-duplicates of corpus documents (2 of 16 tokens
-    # replaced), half fresh documents
-    rng = np.random.default_rng(seed + 3)
-    docs = np.concatenate([np.asarray(b["tokens"]) for b in corpus])
-    n_rows = N_REQUESTS * ROWS_PER_REQUEST
-    src = rng.choice(n_docs, n_rows // 2, replace=False)
-    near = docs[src].copy()
-    near[:, :2] = rng.integers(0, cfg.vocab, (n_rows // 2, 2))
-    fresh = np.asarray(tokens(n_batches, n_rows // 2))
-    perm = rng.permutation(n_rows)
-    rows = np.concatenate([near, fresh]).astype(np.int32)[perm]
-    source = np.concatenate([src, np.full(n_rows // 2, -1)])[perm]
-    is_near = source >= 0
-
-    with timed("embed corpus (reference copy)"):
-        x = jnp.concatenate([svc.embed(b) for b in corpus])
-    group = REQUESTS_PER_BATCH * ROWS_PER_REQUEST
-    q = jnp.concatenate([svc.embed({"tokens": jnp.asarray(
-        rows[i:i + group])}) for i in range(0, n_rows, group)])
-    cos = 1.0 - jnp.matmul(q, x.T, precision=jax.lax.Precision.HIGHEST)
-    to_source = np.asarray(cos)[np.nonzero(is_near)[0], source[is_near]]
-    nearest_fresh = np.asarray(jnp.min(cos[np.nonzero(~is_near)[0]], 1))
-    r = min(2 * float(np.median(to_source)), float(np.median(nearest_fresh)))
-    log(f"radius {r:.6g} (cosine distance: twice the near-duplicates' "
-        f"median distance to their source, {np.median(to_source):.6g}, "
-        f"at most the fresh rows' median distance to their nearest "
-        f"document, {np.median(nearest_fresh):.6g})")
-    rcfg.radius = r       # sizes the SimHash family: set before indexing
-    with timed("index_corpus"):
-        n = svc.index_corpus(corpus)
-    check(n == n_docs, f"indexed {n} of {n_docs} documents")
-
-    with timed("submit + drain_batches"):
-        uids = [svc.submit({"tokens": rows[i * ROWS_PER_REQUEST:
-                                            (i + 1) * ROWS_PER_REQUEST]},
-                           radius=r)
-                for i in range(N_REQUESTS)]
-        check(None not in uids, "admission control shed a request")
-        out = svc.drain_batches(force=True)
-    check(sorted(out) == sorted(uids), "not every request was answered")
-    st = svc.stats
-    log(f"served {st['queries']} query rows in "
-        f"{svc.scheduler.stats()['batches']} coalesced batches; "
-        f"{st['linear_served']} by the linear route")
-    reported = outside = found = 0
-    for k, uid in enumerate(uids):
-        res = out[uid]
-        for j in range(res.n_queries):
-            row, ids = k * ROWS_PER_REQUEST + j, res.ids[j]
-            reported += len(ids)
-            found += int(source[row] in ids)
-            if len(ids):
-                ref = cos[row][jnp.asarray(ids)]
-                tol = 2e-5       # 1e-5 * (|q|^2 + |x|^2), unit vectors
-                outside += int(jnp.sum(ref > r + tol))
-    log(f"  served neighbors: {reported} reported, {outside} outside r; "
-        f"{found} of {int(is_near.sum())} near-duplicates got their "
-        f"source back")
-    check(outside == 0, f"{outside} served neighbors lie outside r")
-    check(reported > 0, "the served path reported no neighbor at all")
-
-
 def four_chip_phase(seed: int, n: int = N, d: int = D) -> None:
     mesh = jax.make_mesh((4,), ("data",))
     log(f"== four-chip phase: ShardedDynamicHybridIndex on mesh "
@@ -496,7 +402,6 @@ def main() -> None:
         four_chip_phase(args.seed)
     else:
         index_phase(args.seed)
-        serving_phase(args.seed)
     for dev in jax.devices():
         peak = dev.memory_stats().get("peak_bytes_in_use")
         log(f"{dev}: peak_bytes_in_use {peak}")

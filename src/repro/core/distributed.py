@@ -124,14 +124,17 @@ def make_query_fn(family, *, num_buckets: int, mesh: Mesh, n_total: int,
         merged = SegmentEstimate(
             collisions=jax.lax.psum(est.collisions, data_axis),
             merged_registers=jax.lax.pmax(merged_local, data_axis),
-            n_live=n_total, n_scan=n_total)
+            n_live=n_total, n_scan=n_total,
+            over_cap=jax.lax.pmax(est.over_cap.astype(jnp.int32),
+                                  data_axis) > 0)
         route_g = finalize_route([merged], cost_model)
         route_l = finalize_route([local], cost_model)
 
         route = route_g if policy == "global" else route_l
         lsh_cost = jnp.sum(route.lsh_cost)
         lin_cost = route.linear_cost * queries.shape[0]
-        use_lsh = lsh_cost < lin_cost                          # scalar/shard
+        # scalar/shard; linear if any row is over the cap
+        use_lsh = (lsh_cost < lin_cost) & ~jnp.any(route.over_cap)
 
         def branch(lsh_route):
             def fn(_):

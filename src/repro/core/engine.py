@@ -71,7 +71,8 @@ class RouteEstimate:
     cand_est: jax.Array     # (Q,) float32 HLL union estimate of candSize
     lsh_cost: jax.Array     # (Q,) float32 Eq. (1)
     linear_cost: Scalar     # scalar       Eq. (2) (traced under shard_map)
-    use_lsh: jax.Array      # (Q,) bool    Algorithm 2 line 4
+    use_lsh: jax.Array      # (Q,) bool    Algorithm 2 line 4, over_cap rows off
+    over_cap: Optional[jax.Array] = None   # (Q,) bool, see SegmentEstimate
 
 
 @dataclasses.dataclass
@@ -94,6 +95,12 @@ class SegmentEstimate:
     cand_exact: Optional[jax.Array] = None         # (Q,) exact distinct
     n_live: Scalar = 0    # live rows this segment contributes
     n_scan: Scalar = 0    # rows its linear scan computes distances over
+    # (Q,) bool: the row's probed buckets hold more rows past this
+    # segment's LSH gather cap than one bucket's worth (the share past
+    # the cap, summed over the buckets exceeds 1), so that route would
+    # lose more than one table of the row's candidates; None for a
+    # segment whose LSH route gathers every colliding row
+    over_cap: Optional[jax.Array] = None
 
 
 class Segment(Protocol):
@@ -115,6 +122,17 @@ class Segment(Protocol):
     # tracing is enabled.
 
 
+@jax.jit
+def _route_rows(lsh_cost, linear_cost, overs):
+    """(Q,) ``use_lsh`` and the segments' ``over_cap`` or-ed (None when
+    no segment flags it), in one dispatch."""
+    use_lsh = lsh_cost < linear_cost
+    if not overs:
+        return use_lsh, None
+    over_cap = functools.reduce(jnp.logical_or, overs)
+    return use_lsh & ~over_cap, over_cap
+
+
 def finalize_route(terms: Sequence[SegmentEstimate], cost_model: CostModel,
                    *, impl: Optional[str] = None,
                    n_live: Optional[Scalar] = None,
@@ -133,6 +151,16 @@ def finalize_route(terms: Sequence[SegmentEstimate], cost_model: CostModel,
     rows cheaply.  LinearCost is priced at ``n_scan``: the rows the
     linear route actually computes distances over (tombstoned or padded
     rows included — masking happens after the scan).
+
+    A row any segment flags ``over_cap`` is sent linear whatever its
+    cost: the LSH route keeps the first ``cap`` rows of each probed
+    bucket, so a neighbour in a bucket of c > cap rows survives with
+    probability cap / c, and a row whose buckets lose more than one
+    table's worth of rows that way (its own neighbourhood denser than
+    the cap) would get an arbitrary part of its answer; only the linear
+    scan answers it in full.  A row that loses at most one table's
+    worth (an unrelated dense bucket hashed beside it) keeps the LSH
+    route: its recall at r is at least what ``L - 1`` tables give.
     """
     assert terms, "finalize_route needs at least one segment"
     collisions = terms[0].collisions
@@ -164,14 +192,46 @@ def finalize_route(terms: Sequence[SegmentEstimate], cost_model: CostModel,
         collisions.astype(jnp.float32), n_live_f))
     lsh_cost = cost_model.lsh_cost(collisions.astype(jnp.float32), cand)
     linear_cost = cost_model.linear_cost(n_scan)
+    use_lsh, over_cap = _route_rows(
+        lsh_cost, linear_cost,
+        tuple(t.over_cap for t in terms if t.over_cap is not None))
     return RouteEstimate(collisions=collisions, cand_est=cand,
                          lsh_cost=lsh_cost, linear_cost=linear_cost,
-                         use_lsh=lsh_cost < linear_cost)
+                         use_lsh=use_lsh, over_cap=over_cap)
 
 
 # ---------------------------------------------------------------------------
 # The CSR+HLL segment (static core and the streaming main segment)
 # ---------------------------------------------------------------------------
+@functools.partial(jax.jit, static_argnames=("cap",))
+def _table_terms(starts, registers, qbuckets, tidx, tomb_counts, *, cap):
+    """A table segment's estimate terms in one dispatch: (Q,) collisions
+    (tombstoned rows subtracted), (Q,) dead collisions or None, the
+    (Q, V, m) probed registers, and (Q,) ``over_cap`` (None when ``cap``
+    is None).  It takes the tables' ``starts``/``registers`` and not
+    the tables, so segments of any row count share one compile."""
+    # an empty perm: the gathers below read starts and registers only
+    tables = LSHTables(perm=starts[:, :0], starts=starts,
+                       registers=registers)
+    counts = bucket_counts(tables, qbuckets, tidx=tidx)
+    regs = gather_registers(tables, qbuckets, tidx=tidx)
+    if tomb_counts is None:
+        collisions = jnp.sum(counts, axis=-1)
+        dead = None
+    else:
+        lidx = table_index(tables, tidx)
+        d = tomb_counts[lidx, qbuckets.astype(jnp.int32)]
+        collisions = jnp.sum(counts - d, axis=-1)
+        dead = jnp.sum(d, axis=-1)
+    # tombstoned rows hold gather slots too: the raw sizes count
+    over = None
+    if cap is not None:
+        lost = jnp.where(counts > cap, 1.0 - cap / jnp.maximum(counts, 1),
+                         0.0)
+        over = jnp.sum(lost, axis=-1) > 1.0
+    return collisions, dead, regs, over
+
+
 @dataclasses.dataclass
 class TableSegment:
     """CSR tables + per-bucket HLLs, with optional tombstones/external ids.
@@ -180,7 +240,10 @@ class TableSegment:
     internal ids reported raw.  The streaming main segment supplies
     ``live``/``tomb_counts`` (tombstone-corrected estimates, dead rows
     masked after search) and ``ext_ids`` (external ids reported, with
-    ``EXT_SENTINEL`` in masked slots).
+    ``EXT_SENTINEL`` in masked slots).  A segment with rows flags the
+    queries whose probed buckets lose more than one bucket's worth of
+    rows to ``cap`` (``over_cap``); an estimate-only one prices
+    Algorithm 2 alone.
     """
 
     tables: LSHTables
@@ -197,21 +260,15 @@ class TableSegment:
     tidx: Optional[jax.Array] = None         # (V,) multi-probe column->table
 
     def estimate_terms(self, qbuckets: jax.Array) -> SegmentEstimate:
-        counts = bucket_counts(self.tables, qbuckets, tidx=self.tidx)
-        regs = gather_registers(self.tables, qbuckets, tidx=self.tidx)
-        if self.tomb_counts is None:
-            collisions = jnp.sum(counts, axis=-1)
-            dead = None
-        else:
-            lidx = table_index(self.tables, self.tidx)
-            d = self.tomb_counts[lidx, qbuckets.astype(jnp.int32)]
-            collisions = jnp.sum(counts - d, axis=-1)
-            dead = jnp.sum(d, axis=-1)
+        collisions, dead, regs, over = _table_terms(
+            self.tables.starts, self.tables.registers, qbuckets, self.tidx,
+            self.tomb_counts, cap=None if self.x is None else self.cap)
         n_rows = self.tables.n if self.x is None else self.x.shape[0]
         n_live = self.tables.n if self.n_live is None else self.n_live
         n_scan = n_rows if self.n_scan is None else self.n_scan
         return SegmentEstimate(collisions=collisions, dead_collisions=dead,
-                               registers=regs, n_live=n_live, n_scan=n_scan)
+                               registers=regs, n_live=n_live, n_scan=n_scan,
+                               over_cap=over)
 
     def search(self, qbuckets: jax.Array, q: jax.Array, r, *,
                lsh_route: bool):

@@ -29,6 +29,7 @@ whole coalesced batch, splits by route, and scatters per-request
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 import time
 from typing import Dict, Iterable, List, Optional, Sequence, Union
@@ -36,6 +37,7 @@ from typing import Dict, Iterable, List, Optional, Sequence, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh
 
 from repro.configs.base import ArchConfig
@@ -51,6 +53,10 @@ from repro.serve.scheduler import ShapeBucketScheduler, TenantQuota
 from repro.streaming import (CompactionDriver, CompactionPolicy,
                              DynamicHybridIndex,
                              ShardedDynamicHybridIndex)
+
+# the encoder as one jitted program, shared by every service of the same
+# (cfg, par) and named in a device trace (``jit_forward_embed``)
+_forward_embed = jax.jit(forward_embed, static_argnames=("cfg", "par"))
 
 
 @dataclasses.dataclass
@@ -122,6 +128,14 @@ class RequestResult:
     when served from the cache).  ``cached`` marks a cache hit;
     ``queue_wait_s`` is the scheduler queue time (0 for hits served at
     submit-batch formation).
+
+    A served (not cached) answer also says how it was computed:
+    ``linear[i]`` is True where the linear route served row i (None for
+    a cache hit, or for a sharded index's per-batch vote), and rows
+    ``row_offset:row_offset + n_queries`` of ``embeddings`` are the
+    request's query embeddings as the index searched them (the whole
+    coalesced group's device array, padding rows included; holding a
+    result keeps it alive).
     """
 
     uid: int
@@ -130,6 +144,9 @@ class RequestResult:
     n_queries: int
     cached: bool
     queue_wait_s: float
+    linear: Optional[np.ndarray] = None
+    embeddings: Optional[jax.Array] = None
+    row_offset: int = 0
 
     def neighbor_sets(self):
         return {i: set(self.ids[i].tolist())
@@ -164,8 +181,7 @@ class RetrievalService:
         # into every service built afterwards
         rcfg = rcfg if rcfg is not None else RetrievalConfig()
         self.cfg, self.par, self.params, self.rcfg = cfg, par, params, rcfg
-        self._embed = jax.jit(
-            lambda p, b: forward_embed(p, b, cfg, par))
+        self._embed = functools.partial(_forward_embed, cfg=cfg, par=par)
         self.index: Optional[Union[DynamicHybridIndex,
                                    ShardedDynamicHybridIndex]] = None
         self.driver: Optional[CompactionDriver] = None
@@ -221,7 +237,9 @@ class RetrievalService:
 
     def embed(self, batch: Dict[str, jax.Array]) -> jax.Array:
         """Normalized (B, d_model) embeddings for one token batch."""
-        return self._embed(self.params, batch)
+        b, s = batch["tokens"].shape
+        with TraceAnnotation("repro.service.embed", rows=b, tokens=b * s):
+            return self._embed(self.params, batch)
 
     def _embed_corpus(self, batches: Iterable[Dict[str, jax.Array]]):
         embs = [np.asarray(self.embed(b)) for b in batches]
@@ -469,32 +487,42 @@ class RetrievalService:
         # they coalesce into one dense pow2 dispatch through the PR 7
         # fused kernels
         groups: Dict[tuple, list] = {}
-        for req in reqs:
-            col = req.collection
-            version = versions.get(col)
-            if version is None:
-                version = self._index_for(col).version
-                self.cache.purge_stale(version, collection=col)
-                versions[col] = version
-            tokens = req.payload["tokens"]
-            radius = req.payload["radius"]
-            key = self.cache.key(version, radius, tokens, collection=col)
-            hit = self.cache.get(key)
-            if hit is not None:
-                ids, dists = hit
-                out[req.uid] = RequestResult(
-                    uid=req.uid, ids=list(ids), dists=list(dists),
-                    n_queries=len(ids), cached=True,
-                    queue_wait_s=req.wait_s)
-                continue
-            groups.setdefault((col, radius, tokens.shape[1]), []).append(
-                (req, key))
-        for (col, radius, _seq), members in groups.items():
-            self._serve_miss_group(col, radius, members, out)
+        rows = sum(req.payload["tokens"].shape[0] for req in reqs)
+        with TraceAnnotation("repro.service.batch", requests=len(reqs),
+                             rows=rows) as span:
+            with TraceAnnotation("repro.service.cache") as lookups:
+                for req in reqs:
+                    col = req.collection
+                    version = versions.get(col)
+                    if version is None:
+                        version = self._index_for(col).version
+                        self.cache.purge_stale(version, collection=col)
+                        versions[col] = version
+                    tokens = req.payload["tokens"]
+                    radius = req.payload["radius"]
+                    key = self.cache.key(version, radius, tokens,
+                                         collection=col)
+                    hit = self.cache.get(key)
+                    if hit is not None:
+                        ids, dists = hit
+                        out[req.uid] = RequestResult(
+                            uid=req.uid, ids=list(ids), dists=list(dists),
+                            n_queries=len(ids), cached=True,
+                            queue_wait_s=req.wait_s)
+                        continue
+                    groups.setdefault((col, radius, tokens.shape[1]),
+                                      []).append((req, key))
+                lookups.set_metadata(hits=len(out),
+                                     misses=len(reqs) - len(out))
+            padded = sum(self._serve_miss_group(col, radius, members, out)
+                         for (col, radius, _seq), members in groups.items())
+            span.set_metadata(padded_rows=padded, groups=len(groups))
         return out
 
     def _serve_miss_group(self, collection: str, radius: float,
-                          members, out) -> None:
+                          members, out) -> int:
+        """One embed + one routed index query for a miss group; returns
+        the rows embedded (padding included)."""
         index = self._index_for(collection)
         rows = np.concatenate([req.payload["tokens"]
                                for req, _ in members], axis=0)
@@ -517,6 +545,10 @@ class RetrievalService:
         self._m_linear.inc(n_linear)
         if collection:
             self.collections.note_query(collection, nq, n_linear)
+        linear = None
+        if hasattr(res, "lin_idx"):
+            linear = np.zeros(n_pad, bool)
+            linear[np.asarray(res.lin_idx, np.int64)] = True
         off = 0
         for req, key in members:
             k = req.payload["tokens"].shape[0]
@@ -528,8 +560,11 @@ class RetrievalService:
             self.cache.put(key, ids, dists)
             out[req.uid] = RequestResult(
                 uid=req.uid, ids=ids, dists=dists, n_queries=k,
-                cached=False, queue_wait_s=req.wait_s)
+                cached=False, queue_wait_s=req.wait_s,
+                linear=None if linear is None else linear[off:off + k],
+                embeddings=emb, row_offset=off)
             off += k
+        return n_pad
 
     @staticmethod
     def _count_linear(res, nq: int) -> int:

@@ -55,6 +55,7 @@ import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.engine import partition_indices
 from repro.obs.metrics import DEFAULT_TIME_BUCKETS, NULL_REGISTRY
@@ -298,28 +299,36 @@ class ShapeBucketScheduler:
         maintenance work (a bounded LSM ``compact_step``, or in
         async-compaction mode the driver's cheap ``drain()``)
         interleaves between query batches even when traffic pauses.
+
+        The call is one profiler span, ``repro.scheduler.next_batch``:
+        ``queued`` requests at the call, ``formed`` the batch's size,
+        ``max_wait_ms`` its longest queue wait (the tick nests inside).
         """
-        now = self.clock()
-        if force and self.queue or self._ready(now):
-            take = self._select(self.max_batch)
-            self._batches += 1
-            self._m_batches.inc()
-            self._m_batch_size.observe(len(take))
-            for req in take:
-                req.wait_s = max(now - req.t_submit, 0.0)
-                self._m_queue_wait.observe(req.wait_s)
-                self._wait_sum += req.wait_s
-                self._wait_max = max(self._wait_max, req.wait_s)
-                st = self._tenant(req.collection)
-                st.batched += 1
-                st.wait_max = max(st.wait_max, req.wait_s)
-            self._requests_batched += len(take)
-        else:
-            take = []
-        if self.background_tick is not None:
-            self._ticks += 1
-            self._m_ticks.inc()
-            self.background_tick()
+        with TraceAnnotation("repro.scheduler.next_batch",
+                             queued=len(self.queue)) as span:
+            now = self.clock()
+            if force and self.queue or self._ready(now):
+                take = self._select(self.max_batch)
+                self._batches += 1
+                self._m_batches.inc()
+                self._m_batch_size.observe(len(take))
+                for req in take:
+                    req.wait_s = max(now - req.t_submit, 0.0)
+                    self._m_queue_wait.observe(req.wait_s)
+                    self._wait_sum += req.wait_s
+                    self._wait_max = max(self._wait_max, req.wait_s)
+                    st = self._tenant(req.collection)
+                    st.batched += 1
+                    st.wait_max = max(st.wait_max, req.wait_s)
+                self._requests_batched += len(take)
+            else:
+                take = []
+            span.set_metadata(formed=len(take), max_wait_ms=max(
+                (req.wait_s for req in take), default=0.0) * 1e3)
+            if self.background_tick is not None:
+                self._ticks += 1
+                self._m_ticks.inc()
+                self.background_tick()
         return take, self._bucket(len(take))
 
     @property

@@ -1070,7 +1070,9 @@ class ShardedDynamicHybridIndex:
                     collisions=jax.lax.psum(m_est.collisions, axis),
                     dead_collisions=jax.lax.psum(m_est.dead_collisions,
                                                  axis),
-                    merged_registers=jax.lax.pmax(merged_local, axis)))
+                    merged_registers=jax.lax.pmax(merged_local, axis),
+                    over_cap=jax.lax.pmax(
+                        m_est.over_cap.astype(jnp.int32), axis) > 0))
                 segments.append(main)
                 n_live_local = n_live_local + jnp.sum(live,
                                                       dtype=jnp.int32)
@@ -1088,8 +1090,10 @@ class ShardedDynamicHybridIndex:
                                      n_scan=n_scan_local)
 
             route = route_g if routing == "global" else route_l
-            use_lsh = (jnp.sum(route.lsh_cost)
-                       < route.linear_cost * queries.shape[0])
+            # one route a batch: linear if any row is over the cap
+            use_lsh = ((jnp.sum(route.lsh_cost)
+                        < route.linear_cost * queries.shape[0])
+                       & ~jnp.any(route.over_cap))
             if force == "lsh":
                 use_lsh = jnp.bool_(True)
             elif force == "linear":

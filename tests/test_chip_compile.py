@@ -9,7 +9,8 @@ tests compile each kernel through its ``ops`` wrapper for a described
 * the linear route, one 32-query chunk of ``linear_search`` against the
   1,000,000 x 128 corpus (l2 and cosine);
 * the LSH route against the same corpus, with L * cap = 20 * 128
-  candidates per query, and at the serving phase's 4096-dim width;
+  candidates per query, and at the served cell's 4096-dim width over
+  its 131,072 documents;
 * the HLL merge + estimate of a 256-query batch, L = 20, m = 64;
 * result extraction's count and pack programs at the benchmark cells'
   group shapes: 16 linear rows of 1,097,729 slots and 16 LSH rows of
@@ -35,7 +36,7 @@ from repro.kernels import ops
 N, D = 1_000_000, 128          # chip_smoke.py index phase
 L, CAP, M = 20, 128, 64
 Q_CHUNK, Q_BATCH = 32, 256
-N_SERVE, D_SERVE = 65_536, 4096  # chip_smoke.py serving phase
+N_SERVE, D_SERVE = 131_072, 4096  # yi6b-served's corpus (chipbench/configs/)
 W_LINEAR, W_LSH = 1_097_729, 24_065  # group widths in the benchmark's cells
 
 

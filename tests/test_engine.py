@@ -8,6 +8,7 @@ exact n_linear).
 """
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core import CostModel, HybridLSHIndex
 from repro.core.engine import (QueryEngine, SegmentEstimate, TableSegment,
@@ -53,6 +54,27 @@ def test_static_estimate_matches_router_wrapper():
                                   np.asarray(c.cand_est))
 
 
+def test_table_terms_one_program_for_any_row_count():
+    """A table segment's estimate terms run as one jitted program whose
+    compile does not depend on the segment's row count (segments of any
+    size share it), and count each row's collisions exactly."""
+    from repro.core import engine
+    x = _data()
+    q = jnp.asarray(x[::60][:8])
+    sizes = []
+    for n in (300, 600):
+        idx = HybridLSHIndex(_fam(), num_buckets=B, m=M, cap=CAP,
+                             key=0).build(x[:n])
+        qb = idx._bucket_fn(idx.params, q)
+        xb = np.asarray(idx._bucket_fn(idx.params, jnp.asarray(x[:n])))
+        seg = TableSegment(tables=idx.tables, x=jnp.asarray(x[:n]), cap=CAP)
+        term = seg.estimate_terms(qb)
+        want = (np.asarray(qb)[:, None, :] == xb[None]).sum(axis=(1, 2))
+        np.testing.assert_array_equal(np.asarray(term.collisions), want)
+        sizes.append(engine._table_terms._cache_size())
+    assert sizes[1] == sizes[0]
+
+
 def test_dynamic_estimate_matches_router_wrapper():
     """Streaming index path == the tombstone-aware compat wrapper."""
     x = _data()
@@ -91,6 +113,48 @@ def test_finalize_route_combines_sketch_and_exact_terms():
                               cand_exact=jnp.asarray([7]),
                               n_live=10, n_scan=10)
     assert float(finalize_route([clamped], cm).cand_est[0]) == 2.0
+
+
+@pytest.mark.parametrize("kind", ["static", "dynamic"])
+def test_rows_over_the_cap_route_linear(kind):
+    """A row whose probed buckets lose more than one bucket's worth of
+    rows to the LSH route's cap (the share past it, summed; deleted rows
+    hold slots too) goes linear whatever the cost model prices, so it
+    gets its whole answer; a row that loses less, or a cap no bucket
+    reaches, keeps its route."""
+    x = _data()
+
+    def index(cap):
+        if kind == "static":
+            return HybridLSHIndex(_fam(), num_buckets=B, m=M, cap=cap,
+                                  key=0).build(x)
+        dyn = DynamicHybridIndex(_fam(), num_buckets=B, m=M, cap=cap, key=0,
+                                 delta_capacity=256)
+        dyn.build(x)
+        dyn.delete(range(0, 600, 15))
+        return dyn
+
+    tight, loose = index(8), index(CAP)
+    q = jnp.asarray(x[1::25])
+    qb = np.asarray(tight._bucket_fn(tight.params, q))
+    xb = np.asarray(tight._bucket_fn(tight.params, jnp.asarray(x)))
+    sizes = (qb[:, None, :] == xb[None, :, :]).sum(axis=1)     # (Q, L)
+    over = np.where(sizes > 8, 1 - 8 / np.maximum(sizes, 1), 0).sum(1) > 1
+    assert over.any() and (~over).any()
+    assert ((sizes > 8).any(axis=1) & ~over).any()     # some, not enough
+    a, b = tight.estimate(q), loose.estimate(q)
+    np.testing.assert_array_equal(np.asarray(a.over_cap), over)
+    assert not np.asarray(b.over_cap).any()
+    np.testing.assert_array_equal(np.asarray(a.use_lsh),
+                                  np.asarray(b.use_lsh) & ~over)
+    hybrid, linear = tight.query(q, R), tight.query(q, R, force="linear")
+    lsh = tight.query(q, R, force="lsh")
+    rows = np.nonzero(over)[0]
+    for i in rows:
+        assert set(hybrid.neighbors(i).tolist()) == set(
+            linear.neighbors(i).tolist())
+    assert any(len(lsh.neighbors(i)) < len(linear.neighbors(i))
+               for i in rows)
 
 
 def test_memory_stats_before_build_is_zeroed():

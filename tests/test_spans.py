@@ -168,3 +168,84 @@ def test_profiling_changes_no_answer(profiled, tmp_path):
     _, _, answers, _ = profiled
     assert [set(a.tolist()) for a, _ in answers] == \
         [set(a.tolist()) for a, _ in plain]
+
+
+# the served path: each span's parent, as above
+SERVED_PARENT = {
+    "repro.scheduler.next_batch": None,
+    "repro.service.batch": None,
+    "repro.service.cache": "repro.service.batch",
+    "repro.service.embed": "repro.service.batch",
+    "repro.index.query": "repro.service.batch",
+}
+DOC_LEN = 8
+
+
+@pytest.fixture(scope="module")
+def served(tmp_path_factory):
+    """A round of three 2-row requests through ``submit`` ->
+    ``drain_batches``, profiled: one repeats a request of the round
+    before (a cache hit), two are new (one miss group of 4 rows, padded
+    to 8)."""
+    from repro.configs import get_config, reduced_config
+    from repro.models import ParallelConfig, init_params
+    from repro.serve import RetrievalConfig, RetrievalService
+
+    arch = reduced_config(get_config("yi-6b"))
+    params = init_params(arch, jax.random.PRNGKey(0))
+    svc = RetrievalService(arch, ParallelConfig(), params, RetrievalConfig(
+        radius=0.5, num_buckets=64, delta_capacity=64))
+    docs = np.random.default_rng(0).integers(0, arch.vocab, (80, DOC_LEN),
+                                             dtype=np.int32)
+    svc.index_corpus([{"tokens": jnp.asarray(docs[:64])}])
+    reqs = [docs[64 + 2 * i:66 + 2 * i] for i in range(4)]
+
+    def round_(batch):
+        for r in batch:
+            svc.submit({"tokens": r})
+        return svc.drain_batches()
+
+    round_(reqs[:2])                    # compile; caches the first two
+    out, spans = _profiled(tmp_path_factory.mktemp("served"),
+                           lambda: round_([reqs[0]] + reqs[2:]))
+    return svc, arch, params, out, spans
+
+
+def test_served_spans_nest_as_listed(served):
+    *_, spans = served
+    assert set(SERVED_PARENT) <= {s[0] for s in spans}
+    for s in spans:
+        if s[0] in SERVED_PARENT:
+            assert _parent(s, spans) == SERVED_PARENT[s[0]], s
+
+
+def test_served_span_counts(served):
+    svc, arch, params, out, spans = served
+    by = {}
+    for s in spans:
+        by.setdefault(s[0], []).append(s[3])
+    assert [r.cached for r in out.values()] == [True, False, False]
+    # the drain forms one batch, then finds the queue empty
+    formed, empty = by["repro.scheduler.next_batch"]
+    assert {k: formed[k] for k in ("queued", "formed")} == \
+        {"queued": 3, "formed": 3}
+    assert formed["max_wait_ms"] >= 0.0
+    assert empty == {"queued": 0, "formed": 0, "max_wait_ms": 0.0}
+    assert by["repro.service.batch"] == [{"requests": 3, "rows": 6,
+                                          "padded_rows": 8, "groups": 1}]
+    assert by["repro.service.cache"] == [{"hits": 1, "misses": 2}]
+    assert by["repro.service.embed"] == [{"rows": 8,
+                                          "tokens": 8 * DOC_LEN}]
+    assert [a["rows"] for a in by["repro.index.query"]] == [8]
+    # a served answer carries its route flags and its query vectors
+    hit, *miss = out.values()
+    assert hit.linear is None and hit.embeddings is None
+    for res in miss:
+        assert res.linear.shape == (2,) and res.embeddings.shape[0] == 8
+    # the encoder runs as one named program
+    from repro.models import ParallelConfig
+    from repro.serve.retrieval import _forward_embed
+    text = _forward_embed.lower(
+        params, {"tokens": jnp.zeros((8, DOC_LEN), jnp.int32)}, cfg=arch,
+        par=ParallelConfig()).as_text()
+    assert "jit_forward_embed" in text
